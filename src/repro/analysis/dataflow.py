@@ -42,7 +42,7 @@ from typing import List, Sequence, Set, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.analysis import rules
 from repro.config.base import ModelConfig
@@ -167,10 +167,11 @@ class _Walker:
                     "(MoE-dispatch permutation invariant)")
             if self.replay and name == "pallas_call":
                 # zero-HBM contract: replay kernels take a (4,)
-                # seed-salt word, never a packed plane
-                for x in eqn.invars:
-                    if _is_mask_aval(getattr(x, "aval", None),
-                                     self.shapes, self.sk, self.sq32):
+                # seed-salt word, never a packed plane (nor a reshaped
+                # view of one, e.g. the premask kernels' block layout)
+                for x, t in zip(eqn.invars, in_t):
+                    if t or _is_mask_aval(getattr(x, "aval", None),
+                                          self.shapes, self.sk, self.sq32):
                         self._finding(
                             record, rules.MASK_OPERAND_REPLAY,
                             "packed mask plane "
@@ -282,7 +283,7 @@ def analyze_jaxpr(closed, cfg: ModelConfig, sched: DropoutSchedule, *,
                      check_residuals, replay=sched.replay)
     jaxpr = closed.jaxpr if isinstance(closed, jcore.ClosedJaxpr) \
         else closed
-    out_t = walker.walk(jaxpr, [False] * len(jaxpr.invars))
+    out_t = walker.walk(jaxpr, [walker._origin(v) for v in jaxpr.invars])
     if check_outputs and any(out_t):
         walker.findings.append(rules.Finding(
             rules.MASK_RESIDUAL_LEAK,

@@ -131,7 +131,7 @@ def attention_pallas(q, k, v, *, causal=True, local_window=0,
                      layer_salt: int = 0, seed: int = 0,
                      packed_mask=None, block_q=128, block_k=128):
     """Flash-attention Pallas kernel path (static seed/salt — see DESIGN)."""
-    from repro.kernels import default_interpret, flash_attention
+    from repro.kernels import flash_attention
     dropped = plan is not None and plan.enabled
     mode = "none"
     p = 0.0
@@ -142,7 +142,7 @@ def attention_pallas(q, k, v, *, causal=True, local_window=0,
         mode = "premask" if packed_mask is not None else "fused"
     return flash_attention(
         q, k, v, packed_mask, causal, local_window, p, mode, seed,
-        layer_salt, rounds, block_q, block_k, default_interpret())
+        layer_salt, rounds, block_q, block_k, None)
 
 
 def attention_decode(q1: jnp.ndarray, k_cache: jnp.ndarray,

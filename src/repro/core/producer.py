@@ -37,7 +37,7 @@ kernel's own layout check stays authoritative at run time) → xla.
 Fallback chain for replay consumption: replay → premask → xla.
 
 With a sharding policy installed, the kernel producers run SHARD-LOCAL
-inside ``compat.shard_map``: each shard generates its (b_loc, h_loc)
+inside ``jax.shard_map``: each shard generates its (b_loc, h_loc)
 tile of the mask plane under its slice of the host GEMM. The Philox
 counter scheme is position-based (philox_common.global_bh), so
 shard-local bits equal the global mask's slice exactly.
@@ -61,12 +61,12 @@ so a divergence between planner and kernels fails fast.
 from __future__ import annotations
 
 import dataclasses
+import logging
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.config.base import FFNKind, ModelConfig
 from repro.core import dropout_rng
 from repro.core.overlap import DropoutPlan
@@ -82,6 +82,8 @@ HOW_XLA = "xla"
 # (HostAssignment.host_how) so the RNG still hides under the GEMM and
 # the bits stay contract-identical to what the consumer derives.
 HOW_REPLAY = "replay"
+
+log = logging.getLogger(__name__)
 
 # interpret-mode-friendly caps, matching the fused kernel's defaults
 _BLOCK_M_CAP = 256
@@ -229,6 +231,19 @@ def replay_unsupported_reason(plan: DropoutPlan, sq: int, sk: int,
     return None
 
 
+def note_realized(planned: Optional[str], realized: str,
+                  where: str) -> str:
+    """Return ``realized``, warning when it is not the schedule's
+    ``planned`` producer: a runtime fallback (replay -> premask -> xla,
+    gemm -> standalone -> xla) keeps the bits but moves the work, and
+    must not pass silently. ``planned`` None (a direct call that let the
+    executor decide) never warns."""
+    if planned is not None and realized != planned:
+        log.warning("%s: planned mask producer %r ran as %r", where,
+                    planned, realized)
+    return realized
+
+
 # --------------------------------------------------------------------------
 # shard-local execution context
 # --------------------------------------------------------------------------
@@ -334,7 +349,7 @@ def standalone_packed_mask(plan: DropoutPlan, batch: int, n_heads: int,
                 plan.cfg.philox_rounds, heads_global=hg,
                 bh_offset=off)
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=shard.mesh, in_specs=(P(), P()),
             out_specs=P(shard.b_spec, shard.h_spec, None, None),
             check_vma=False,
@@ -419,14 +434,16 @@ def gemm_with_mask(x2d: jnp.ndarray, w2d: jnp.ndarray, plan: DropoutPlan,
 
     shard = shard_exec(policy, batch, n_heads)
     if shard is not None:
-        return _gemm_with_mask_sharded(x2d, w2d, plan, mask_shape,
-                                       layer_idx, step, shard)
+        y, mask, done = _gemm_with_mask_sharded(x2d, w2d, plan, mask_shape,
+                                                layer_idx, step, shard)
+        return y, mask, note_realized(how, done, "gemm_with_mask")
 
     blocks = pick_gemm_blocks(m, n, kdim)
     if blocks is None:
         # planned a kernel host on an untileable GEMM — only reachable
         # from direct calls that bypass the compiler; degrade like it
         # would have planned
+        note_realized(how, HOW_XLA, "gemm_with_mask")
         return gemm_with_mask(x2d, w2d, plan, mask_shape, layer_idx,
                               step, how=HOW_XLA)
     seed = plan.step_seed(step)
@@ -440,6 +457,8 @@ def gemm_with_mask(x2d: jnp.ndarray, w2d: jnp.ndarray, plan: DropoutPlan,
         # check stays authoritative at run time.
         mask = standalone_packed_mask(plan, batch, n_heads, sq, sk,
                                       layer_idx, step)
+        if how == HOW_GEMM:
+            note_realized(how, HOW_STANDALONE, "gemm_with_mask")
         return y, mask, HOW_STANDALONE
     return y, mask, HOW_GEMM
 
@@ -501,7 +520,7 @@ def _gemm_with_mask_sharded(x2d, w2d, plan, mask_shape, layer_idx, step,
                 bh_offset=off)
         return y, mask
 
-    y, mask = shard_map(
+    y, mask = jax.shard_map(
         body, mesh=shard.mesh, in_specs=(xs, ws, P(), P()),
         out_specs=(ys, ms), check_vma=False,
     )(x2d, w2d, seed, salt)
@@ -628,11 +647,14 @@ def grouped_gemm_with_mask(a3: jnp.ndarray, b3: jnp.ndarray,
         return y, mask, HOW_STANDALONE
     shard = shard_exec(policy, batch, n_heads)
     if shard is not None:
-        return _grouped_gemm_with_mask_sharded(a3, b3, plan, mask_shape,
-                                               layer_idx, step, shard)
-    seed = jnp.asarray(plan.step_seed(step), jnp.uint32)
-    salt = jnp.asarray(plan.salt(layer_idx), jnp.uint32)
-    return grouped_gemm_seeded(a3, b3, plan, mask_shape, seed, salt)
+        y, mask, done = _grouped_gemm_with_mask_sharded(
+            a3, b3, plan, mask_shape, layer_idx, step, shard)
+    else:
+        seed = jnp.asarray(plan.step_seed(step), jnp.uint32)
+        salt = jnp.asarray(plan.salt(layer_idx), jnp.uint32)
+        y, mask, done = grouped_gemm_seeded(a3, b3, plan, mask_shape,
+                                            seed, salt)
+    return y, mask, note_realized(how, done, "grouped_gemm_with_mask")
 
 
 def _grouped_gemm_with_mask_sharded(a3, b3, plan, mask_shape, layer_idx,
@@ -663,7 +685,7 @@ def _grouped_gemm_with_mask_sharded(a3, b3, plan, mask_shape, layer_idx,
             a_, b_, plan, local_shape, sd_, sl_,
             heads_global=hg, bh_offset=off)[:2]
 
-    y, mask = shard_map(
+    y, mask = jax.shard_map(
         body, mesh=shard.mesh,
         in_specs=(xs, P(None, None, None), P(), P()),
         out_specs=(xs, ms), check_vma=False,

@@ -97,7 +97,7 @@ class HostAssignment:
       host_how — replay only: the retained run-and-discard host
                  realization (HOW_GEMM / HOW_GEMM_GROUPED — the GEMM
                  still hides the RNG; "" = no host GEMM retained)
-      sharded  — production runs shard-local inside compat.shard_map
+      sharded  — production runs shard-local inside jax.shard_map
                  (for HOW_REPLAY: consumption replays shard-local
                  counter windows inside the attention shard_map)
       reason   — why ``how`` degraded from the fused kernel ("" = fused

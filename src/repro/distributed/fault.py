@@ -16,7 +16,7 @@ import dataclasses
 import os
 import threading
 import time
-from typing import Callable, Deque, List, Optional
+from typing import Callable, Deque, Dict, List, Optional
 
 
 class StragglerDetector:
@@ -104,6 +104,10 @@ class RunnerReport:
     # crashes: the run fell back to the previous checkpoint, no
     # restart-budget slot was burned)
     failed_saves: int = 0
+    # loss of every step of the final trajectory, keyed by the step
+    # index it was computed at (a step replayed after a recovery keeps
+    # its replayed value)
+    losses: Dict[int, float] = dataclasses.field(default_factory=dict)
 
 
 class TrainRunner:
@@ -187,6 +191,7 @@ class TrainRunner:
         import jax
         from repro.checkpoint.contract import ContractMismatchError
         metrics = {}
+        losses: Dict[int, float] = {}
         step = int(jax.device_get(self.state["step"]))
         while step < n_steps:
             try:
@@ -197,6 +202,7 @@ class TrainRunner:
                 self.state, metrics = self.step_fn(self.state, x, y)
                 jax.block_until_ready(metrics["loss"])
                 self.straggler.observe(time.perf_counter() - t0)
+                losses[step] = float(metrics["loss"])
                 step += 1
                 if step % self.checkpoint_every == 0:
                     self._save(step)
@@ -223,4 +229,5 @@ class TrainRunner:
             restarts=self.restarts,
             straggler_steps=len(self.straggler.flagged),
             failed_saves=self.failed_saves,
-            final_metrics={k: float(v) for k, v in metrics.items()})
+            final_metrics={k: float(v) for k, v in metrics.items()},
+            losses=losses)

@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 
 def pipeline_apply(stage_fn: Callable, stacked_params, x_micro, mesh,
                    pp_axis: str = "pp"):
@@ -69,7 +67,7 @@ def pipeline_apply(stage_fn: Callable, stacked_params, x_micro, mesh,
         return jax.lax.psum(outs * is_last, pp_axis)
 
     param_specs = jax.tree.map(lambda _: P(pp_axis), stacked_params)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(param_specs, P()),
         out_specs=P(),

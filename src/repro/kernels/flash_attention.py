@@ -42,6 +42,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.philox_common import (
     global_bh,
     philox4x32,
@@ -146,7 +147,7 @@ def _flash_kernel(*refs, bq: int, bk: int, d: int, n_heads: int,
                                   bq, bk, rounds)
             p_acc = jnp.where(keep, p, 0.0)
         elif mode == "premask":
-            packed = mask_ref[0, 0]                   # (bq//32, bk)
+            packed = mask_ref[0, 0, 0]                # (bq//32, bk)
             keep = unpack_bits_q32(packed, bq)
             p_acc = jnp.where(keep, p, 0.0)
         else:
@@ -164,8 +165,12 @@ def _flash_kernel(*refs, bq: int, bk: int, d: int, n_heads: int,
         out = acc_scr[...] / l * inv_keep
         o_ref[...] = out[None, None].astype(out_dtype)
         if lse_ref is not None:
-            lse = m_scr[...][:, 0] + jnp.log(l[:, 0])
-            lse_ref[...] = lse[None, None].astype(jnp.float32)
+            # the running stats are lane-broadcast (bq, 128) tiles; one
+            # 2-D transpose turns them into the lane-dense (1, bq) row
+            # the (B, H, 1, SQ) lse layout stores
+            l_all = l_scr[...]
+            lse = m_scr[...] + jnp.log(jnp.where(l_all == 0.0, 1.0, l_all))
+            lse_ref[...] = lse.T[:1][None, None]
 
 
 def _check_premask(mask_packed, batch, n_heads, sq, sk):
@@ -200,6 +205,14 @@ def _check_replay_operand(seed_salt):
     return seed_salt
 
 
+def premask_blocks(mask_packed, bq: int):
+    """(B, H, SQ//32, SK) packed plane viewed as (B, H, SQ//bq, bq//32,
+    SK): a free row-major reshape whose trailing (bq//32, bk) block is
+    legal for Mosaic at any bq. Shared by the fwd and both bwd passes."""
+    b, h, sq32, sk = mask_packed.shape
+    return mask_packed.reshape(b, h, sq32 * 32 // bq, bq // 32, sk)
+
+
 def replay_keep_plane(seed_salt, batch: int, n_heads: int, sq: int,
                       sk: int, dropout_p: float, rounds: int = 7,
                       heads_global: int = 0) -> jnp.ndarray:
@@ -227,7 +240,7 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         seed: int = 0, salt: int = 0, rounds: int = 7,
                         scale: Optional[float] = None,
                         block_q: int = 128, block_k: int = 128,
-                        interpret: bool = True,
+                        interpret: Optional[bool] = None,
                         heads_global: int = 0,
                         return_lse: bool = False):
     """Forward flash attention. q: (B,H,SQ,D); k,v: (B,KV,SK,D).
@@ -236,7 +249,8 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     canonical counter scheme. mode "replay" takes the (4,) uint32
     seed-salt operand in the mask_packed slot (built from seed/salt when
     omitted); ``heads_global`` (0 = n_heads) makes a shard-local call
-    replay global-position counters.
+    replay global-position counters. ``return_lse`` adds the f32
+    log-sum-exp of every score row, laid out lane-dense as (B,H,1,SQ).
     """
     batch, n_heads, sq, d = q.shape
     kv_heads, sk = k.shape[1], k.shape[2]
@@ -267,9 +281,12 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     in_specs = [q_spec, kv_spec, kv_spec]
     args = [q, k, v]
     if mode == "premask":
-        in_specs.append(pl.BlockSpec((1, 1, bq // 32, bk),
-                                     lambda b, h, qi, ki: (b, h, qi, ki)))
-        args.append(mask_packed)
+        # a (bq//32, bk) slab of (SQ//32, SK) is not (8, 128)-aligned for
+        # bq = 128; split SQ//32 into (SQ//bq, bq//32) so the block's
+        # second-minor dim spans its whole array dim (same words)
+        in_specs.append(pl.BlockSpec((1, 1, 1, bq // 32, bk),
+                                     lambda b, h, qi, ki: (b, h, qi, 0, ki)))
+        args.append(premask_blocks(mask_packed, bq))
     elif mode == "replay":
         # the whole dropout state: 16 bytes of SMEM, not a q*k plane
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
@@ -288,10 +305,10 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     out_shape = jax.ShapeDtypeStruct((batch, n_heads, sq, d), q.dtype)
     if return_lse:
         out_specs = [o_spec,
-                     pl.BlockSpec((1, 1, bq),
-                                  lambda b, h, qi, ki: (b, h, qi))]
+                     pl.BlockSpec((1, 1, 1, bq),
+                                  lambda b, h, qi, ki: (b, h, 0, qi))]
         out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((batch, n_heads, sq),
+                     jax.ShapeDtypeStruct((batch, n_heads, 1, sq),
                                           jnp.float32)]
     # the named_scope marks interpret-mode emulation loops so the
     # roofline analyzer charges this region by its call-boundary I/O
@@ -299,6 +316,7 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     with jax.named_scope("pallas_kernel_region"):
         return pl.pallas_call(
             kernel,
+            name="flash_fwd",
             grid=grid,
             in_specs=in_specs,
             out_specs=out_specs,
@@ -308,7 +326,7 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                 pltpu.VMEM((bq, 128), jnp.float32),   # running denom l
                 pltpu.VMEM((bq, d), jnp.float32),     # output accumulator
             ],
-            interpret=interpret,
+            interpret=resolve_interpret(interpret),
         )(*args)
 
 
@@ -317,7 +335,7 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14))
 def flash_attention(q, k, v, mask_packed=None, causal=True, local_window=0,
                     dropout_p=0.0, mode="none", seed=0, salt=0, rounds=7,
-                    block_q=128, block_k=128, interpret=True,
+                    block_q=128, block_k=128, interpret=None,
                     heads_global=0):
     """Differentiable flash attention (forward = Pallas kernel; backward =
     the mathematically identical reference formulas, reusing the same
@@ -391,7 +409,7 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 def flash_attention_mosaic(q, k, v, mask_packed=None, causal=True,
                            local_window=0, dropout_p=0.0, mode="none",
                            seed=0, salt=0, rounds=7, block_q=128,
-                           block_k=128, interpret=True, heads_global=0):
+                           block_k=128, interpret=None, heads_global=0):
     """Flash attention with Pallas forward *and* backward kernels —
     nothing O(SQ*SK) ever reaches HBM in either direction. In "premask"
     mode (the paper's overlap technique) the dropout bits come from HBM,

@@ -32,6 +32,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import resolve_interpret
+from repro.kernels.flash_attention import premask_blocks
 from repro.kernels.philox_common import (
     global_bh,
     seed_salt_smem,
@@ -59,10 +61,16 @@ def _mask_and_p(s, lse_blk, q_start, k_start, bq, bk, causal,
     return jnp.exp(s - lse_blk)
 
 
+def _row_to_col(row, bq):
+    """(1, bq) lane-dense row -> (bq, 1) column: a sublane broadcast and
+    a 2-D transpose, both of which Mosaic lowers."""
+    return jnp.broadcast_to(row, (128, bq)).T[:, :1]
+
+
 def _keep_tile(mode, mask_ref, q_start, k_start, bh, bq, bk, salt, k0, k1,
                threshold, rounds, heads_local=0, heads_global=0):
     if mode == "premask":
-        return unpack_bits_q32(mask_ref[0, 0], bq)
+        return unpack_bits_q32(mask_ref[0, 0, 0], bq)
     if mode == "replay":
         # mask_ref is the (4,) uint32 [k0, k1, salt, bh_offset] SMEM
         # operand — replay the forward tile's counters in-register
@@ -108,8 +116,8 @@ def _dq_kernel(*refs, bq, bk, scale, causal, local_window, q_offset,
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0].astype(jnp.float32).reshape(bq, 1)
-        delta = delta_ref[0, 0].astype(jnp.float32).reshape(bq, 1)
+        lse = _row_to_col(lse_ref[0, 0], bq)
+        delta = _row_to_col(delta_ref[0, 0], bq)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         p = _mask_and_p(s, lse, q_start, k_start, bq, bk, causal,
@@ -169,8 +177,8 @@ def _dkv_kernel(*refs, bq, bk, scale, causal, local_window, q_offset,
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0].astype(jnp.float32).reshape(bq, 1)
-        delta = delta_ref[0, 0].astype(jnp.float32).reshape(bq, 1)
+        lse = _row_to_col(lse_ref[0, 0], bq)
+        delta = _row_to_col(delta_ref[0, 0], bq)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         p = _mask_and_p(s, lse, q_start, k_start, bq, bk, causal,
@@ -208,7 +216,7 @@ def flash_attention_bwd(q, k, v, o, lse, do,
                         causal=True, local_window=0, dropout_p=0.0,
                         mode="none", seed=0, salt=0, rounds=7,
                         scale=None, block_q=128, block_k=128,
-                        interpret=True,
+                        interpret=None,
                         heads_global=0) -> Tuple[jnp.ndarray, jnp.ndarray,
                                                  jnp.ndarray]:
     """Returns (dq, dk, dv). k/v gradients are computed per q-head and
@@ -237,8 +245,12 @@ def flash_attention_bwd(q, k, v, o, lse, do,
                   heads_local=n_heads,
                   heads_global=heads_global or n_heads)
 
+    # per-row stats ride lane-dense as (B, H, 1, SQ), like the fwd lse
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)  # (B,H,SQ)
+                    axis=-1)[:, :, None, :]
+    interpret = resolve_interpret(interpret)
+    if mode == "premask":
+        mask_packed = premask_blocks(mask_packed, bq)
 
     q_spec = pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0))
     kq_spec = pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, j, 0))
@@ -246,12 +258,13 @@ def flash_attention_bwd(q, k, v, o, lse, do,
                            lambda b, h, i, j: (b, h // group, j, 0))
     kvk_spec = pl.BlockSpec((1, 1, bk, d),
                             lambda b, h, i, j: (b, h // group, i, 0))
-    row_spec = pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i))
-    rowq_spec = pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, j))
-    mask_spec = pl.BlockSpec((1, 1, bq // 32, bk),
-                             lambda b, h, i, j: (b, h, i, j))
-    maskk_spec = pl.BlockSpec((1, 1, bq // 32, bk),
-                              lambda b, h, i, j: (b, h, j, i))
+    row_spec = pl.BlockSpec((1, 1, 1, bq), lambda b, h, i, j: (b, h, 0, i))
+    rowq_spec = pl.BlockSpec((1, 1, 1, bq),
+                             lambda b, h, i, j: (b, h, 0, j))
+    mask_spec = pl.BlockSpec((1, 1, 1, bq // 32, bk),
+                             lambda b, h, i, j: (b, h, i, 0, j))
+    maskk_spec = pl.BlockSpec((1, 1, 1, bq // 32, bk),
+                              lambda b, h, i, j: (b, h, j, 0, i))
 
     # ---- dq pass: grid (B, H, nq, nk) --------------------------------
     in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
@@ -265,6 +278,7 @@ def flash_attention_bwd(q, k, v, o, lse, do,
     with jax.named_scope("pallas_kernel_region"):
         dq = pl.pallas_call(
             functools.partial(_dq_kernel, **common),
+            name="flash_bwd_dq",
             grid=(batch, n_heads, sq // bq, sk // bk),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, 1, bq, d),
@@ -287,6 +301,7 @@ def flash_attention_bwd(q, k, v, o, lse, do,
     with jax.named_scope("pallas_kernel_region"):
         dk_h, dv_h = pl.pallas_call(
             functools.partial(_dkv_kernel, **common),
+            name="flash_bwd_dkv",
             grid=(batch, n_heads, sk // bk, sq // bq),
             in_specs=in_specs,
             out_specs=[

@@ -32,6 +32,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import quant
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.philox_common import (
     packed_rows_tile,
     seed_salt_smem,
@@ -140,6 +141,34 @@ def _mask_block_idx(s, n_valid_blocks: int, n_cb: int, n_rb_valid: int):
     return rb_idx, cb_idx
 
 
+# A mask block is emitted in (8, 512)-word chunks — the standalone
+# kernel's block shape — so the Philox temporaries, (64, 512) uint32
+# arrays, stay a few hundred KiB of VMEM whatever (rb, ck) the layout
+# picked. Each chunk's words depend only on its global position.
+_EMIT_ROWS = 8
+_EMIT_COLS = 512
+
+
+def _emit_mask_block(m_ref, s_ref, r_start, c_start, *, rb: int, ck: int,
+                     sq32: int, threshold: int, rounds: int,
+                     heads_local: int, heads_global: int):
+    """Write the (rb, ck) packed-mask block whose top-left word is global
+    packed row ``r_start``, column ``c_start`` into ``m_ref``."""
+    cc = _EMIT_COLS if ck % _EMIT_COLS == 0 else ck
+    n_c = ck // cc
+
+    def chunk(t, carry):
+        r = (t // n_c) * _EMIT_ROWS
+        c = (t % n_c) * cc
+        m_ref[pl.ds(r, _EMIT_ROWS), pl.ds(c, cc)] = packed_rows_tile(
+            r_start + r, c_start + c, sq32, s_ref[2], s_ref[0], s_ref[1],
+            threshold, _EMIT_ROWS, cc, rounds, heads_local=heads_local,
+            heads_global=heads_global, bh_offset=s_ref[3])
+        return carry
+
+    jax.lax.fori_loop(0, (rb // _EMIT_ROWS) * n_c, chunk, 0)
+
+
 def _gemm_rng_kernel(s_ref, a_ref, b_ref, c_ref, m_ref, acc_scr, *,
                      n_cb: int, rb: int, ck: int, sq32: int,
                      threshold: int, rounds: int,
@@ -166,10 +195,10 @@ def _gemm_rng_kernel(s_ref, a_ref, b_ref, c_ref, m_ref, acc_scr, *,
         s = i * gn + j
         rb_idx, cb_idx = _mask_block_idx(s, n_valid_blocks, n_cb,
                                          n_rb_valid)
-        m_ref[...] = packed_rows_tile(
-            rb_idx * rb, cb_idx * ck, sq32, s_ref[2], s_ref[0], s_ref[1],
-            threshold, rb, ck, rounds, heads_local=heads_local,
-            heads_global=heads_global, bh_offset=s_ref[3])
+        _emit_mask_block(m_ref, s_ref, rb_idx * rb, cb_idx * ck, rb=rb,
+                         ck=ck, sq32=sq32, threshold=threshold,
+                         rounds=rounds, heads_local=heads_local,
+                         heads_global=heads_global)
 
     @pl.when(kk == nk - 1)
     def _flush():
@@ -183,7 +212,7 @@ def gemm_with_rng(a: jnp.ndarray, b: jnp.ndarray, *,
                   block_m: int = 256, block_n: int = 256,
                   block_k: int = 512, mask_block_cols: int = 2048,
                   max_mask_rows_per_block: int = 256,
-                  interpret: bool = True,
+                  interpret: Optional[bool] = None,
                   heads_global: int = 0, bh_offset=0,
                   ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
     """C = a @ b, plus the packed dropout keep-mask (B, H, SQ//32, SK)
@@ -199,6 +228,7 @@ def gemm_with_rng(a: jnp.ndarray, b: jnp.ndarray, *,
     m, kdim = a.shape
     k2, n = b.shape
     assert kdim == k2
+    interpret = resolve_interpret(interpret)
     bm, bn, bkk = min(block_m, m), min(block_n, n), min(block_k, kdim)
     assert m % bm == 0 and n % bn == 0 and kdim % bkk == 0
     gm, gn, gk = m // bm, n // bn, kdim // bkk
@@ -240,6 +270,7 @@ def _gemm_rng_impl(static, sd, a, b):
 
     c, mask2d = pl.pallas_call(
         kernel,
+        name="gemm_rng",
         grid=(gm, gn, gk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -318,6 +349,7 @@ def _plain_gemm_impl(a, b, static):
 
     return pl.pallas_call(
         kern,
+        name="gemm",
         grid=(m // bm, n // bn, kdim // bkk),
         in_specs=[
             pl.BlockSpec((bm, bkk), lambda i, j, kk: (i, kk)),
@@ -397,10 +429,10 @@ def _gemm_rng_fp8_kernel(s_ref, as_ref, bs_ref, a_ref, b_ref, c_ref,
         s = i * gn + j
         rb_idx, cb_idx = _mask_block_idx(s, n_valid_blocks, n_cb,
                                          n_rb_valid)
-        m_ref[...] = packed_rows_tile(
-            rb_idx * rb, cb_idx * ck, sq32, s_ref[2], s_ref[0], s_ref[1],
-            threshold, rb, ck, rounds, heads_local=heads_local,
-            heads_global=heads_global, bh_offset=s_ref[3])
+        _emit_mask_block(m_ref, s_ref, rb_idx * rb, cb_idx * ck, rb=rb,
+                         ck=ck, sq32=sq32, threshold=threshold,
+                         rounds=rounds, heads_local=heads_local,
+                         heads_global=heads_global)
 
     @pl.when(kk == nk - 1)
     def _flush():
@@ -414,7 +446,7 @@ def gemm_with_rng_fp8(a: jnp.ndarray, b: jnp.ndarray, *,
                       block_m: int = 256, block_n: int = 256,
                       block_k: int = 512, mask_block_cols: int = 2048,
                       max_mask_rows_per_block: int = 256,
-                      interpret: bool = True,
+                      interpret: Optional[bool] = None,
                       heads_global: int = 0, bh_offset=0,
                       ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
     """C ~= a @ b computed on per-tile-scaled e4m3 operands, plus the
@@ -432,6 +464,7 @@ def gemm_with_rng_fp8(a: jnp.ndarray, b: jnp.ndarray, *,
     m, kdim = a.shape
     k2, n = b.shape
     assert kdim == k2
+    interpret = resolve_interpret(interpret)
     bm, bn, bkk = min(block_m, m), min(block_n, n), min(block_k, kdim)
     assert m % bm == 0 and n % bn == 0 and kdim % bkk == 0
     gm, gn, gk = m // bm, n // bn, kdim // bkk
@@ -475,6 +508,7 @@ def _gemm_rng_fp8_impl(static, sd, a, b):
 
     c, mask2d = pl.pallas_call(
         kernel,
+        name="gemm_rng_fp8",
         grid=(gm, gn, gk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -577,10 +611,10 @@ def _gemm_rng_grouped_kernel(s_ref, a_ref, b_ref, c_ref, m_ref, acc_scr, *,
         s = (e * gm + i) * gn + j
         rb_idx, cb_idx = _mask_block_idx(s, n_valid_blocks, n_cb,
                                          n_rb_valid)
-        m_ref[...] = packed_rows_tile(
-            rb_idx * rb, cb_idx * ck, sq32, s_ref[2], s_ref[0], s_ref[1],
-            threshold, rb, ck, rounds, heads_local=heads_local,
-            heads_global=heads_global, bh_offset=s_ref[3])
+        _emit_mask_block(m_ref, s_ref, rb_idx * rb, cb_idx * ck, rb=rb,
+                         ck=ck, sq32=sq32, threshold=threshold,
+                         rounds=rounds, heads_local=heads_local,
+                         heads_global=heads_global)
 
     @pl.when(kk == nk - 1)
     def _flush():
@@ -594,7 +628,7 @@ def gemm_with_rng_grouped(a: jnp.ndarray, b: jnp.ndarray, *,
                           block_m: int = 256, block_n: int = 256,
                           block_k: int = 512, mask_block_cols: int = 2048,
                           max_mask_rows_per_block: int = 256,
-                          interpret: bool = True,
+                          interpret: Optional[bool] = None,
                           heads_global: int = 0, bh_offset=0,
                           ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
     """C[e] = a[e] @ b[e] for a (E, C, K), b (E, K, N), plus the packed
@@ -611,6 +645,7 @@ def gemm_with_rng_grouped(a: jnp.ndarray, b: jnp.ndarray, *,
     e, c, kdim = a.shape
     e2, k2, n = b.shape
     assert e == e2 and kdim == k2
+    interpret = resolve_interpret(interpret)
     bm, bn, bkk = min(block_m, c), min(block_n, n), min(block_k, kdim)
     assert c % bm == 0 and n % bn == 0 and kdim % bkk == 0
     gm, gn, gk = c // bm, n // bn, kdim // bkk
@@ -652,6 +687,7 @@ def _gemm_rng_grouped_impl(static, sd, a, b):
 
     cc, mask2d = pl.pallas_call(
         kernel,
+        name="gemm_rng_grouped",
         grid=(e, gm, gn, gk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -725,6 +761,7 @@ def _plain_grouped_impl(a, b, static):
 
     return pl.pallas_call(
         kern,
+        name="gemm_grouped",
         grid=(e, c // bm, n // bn, kdim // bkk),
         in_specs=[
             pl.BlockSpec((1, bm, bkk), lambda ei, i, j, kk: (ei, i, kk)),
@@ -790,10 +827,10 @@ def _gemm_rng_grouped_fp8_kernel(s_ref, as_ref, bs_ref, a_ref, b_ref,
         s = (e * gm + i) * gn + j
         rb_idx, cb_idx = _mask_block_idx(s, n_valid_blocks, n_cb,
                                          n_rb_valid)
-        m_ref[...] = packed_rows_tile(
-            rb_idx * rb, cb_idx * ck, sq32, s_ref[2], s_ref[0], s_ref[1],
-            threshold, rb, ck, rounds, heads_local=heads_local,
-            heads_global=heads_global, bh_offset=s_ref[3])
+        _emit_mask_block(m_ref, s_ref, rb_idx * rb, cb_idx * ck, rb=rb,
+                         ck=ck, sq32=sq32, threshold=threshold,
+                         rounds=rounds, heads_local=heads_local,
+                         heads_global=heads_global)
 
     @pl.when(kk == nk - 1)
     def _flush():
@@ -808,7 +845,7 @@ def gemm_with_rng_grouped_fp8(a: jnp.ndarray, b: jnp.ndarray, *,
                               block_k: int = 512,
                               mask_block_cols: int = 2048,
                               max_mask_rows_per_block: int = 256,
-                              interpret: bool = True,
+                              interpret: Optional[bool] = None,
                               heads_global: int = 0, bh_offset=0,
                               ) -> Tuple[jnp.ndarray,
                                          Optional[jnp.ndarray]]:
@@ -828,6 +865,7 @@ def gemm_with_rng_grouped_fp8(a: jnp.ndarray, b: jnp.ndarray, *,
     e, c, kdim = a.shape
     e2, k2, n = b.shape
     assert e == e2 and kdim == k2
+    interpret = resolve_interpret(interpret)
     bm, bn, bkk = min(block_m, c), min(block_n, n), min(block_k, kdim)
     assert c % bm == 0 and n % bn == 0 and kdim % bkk == 0
     gm, gn, gk = c // bm, n // bn, kdim // bkk
@@ -874,6 +912,7 @@ def _gemm_rng_grouped_fp8_impl(static, sd, a, b):
 
     cc, mask2d = pl.pallas_call(
         kernel,
+        name="gemm_rng_grouped_fp8",
         grid=(e, gm, gn, gk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -958,6 +997,7 @@ def _plain_gemm_fp8_impl(a, b, static):
     b_q, b_s = quant.quantize_tiled(b, bkk, bn)
     return pl.pallas_call(
         functools.partial(_plain_fp8_kernel, out_dtype=a.dtype),
+        name="gemm_fp8",
         grid=(m // bm, n // bn, kdim // bkk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

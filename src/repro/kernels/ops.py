@@ -1,15 +1,17 @@
-"""Public jit'd entry points for the Pallas kernels.
+"""Public entry points for the Pallas kernels.
 
-``interpret`` defaults to True when no TPU is present (this container), so
-the same call sites run on CPU for validation and compile to Mosaic on TPU.
+The kernels resolve their execution mode through
+``backend.default_interpret``: Mosaic on a TPU, the Pallas interpreter on
+any other backend, so the same call sites validate on CPU and compile for
+the chip.
 """
 from __future__ import annotations
 
 from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import default_interpret
 from repro.kernels.flash_attention import flash_attention, flash_attention_fwd
 from repro.kernels.gemm_rng import (
     gemm_with_rng,
@@ -35,10 +37,6 @@ __all__ = [
 ]
 
 
-def default_interpret() -> bool:
-    return jax.devices()[0].platform != "tpu"
-
-
 def dropout_mask(batch: int, n_heads: int, sq: int, sk: int, p: float,
                  seed, salt=0, rounds: int = 7, heads_global: int = 0,
                  bh_offset=0) -> jnp.ndarray:
@@ -47,8 +45,7 @@ def dropout_mask(batch: int, n_heads: int, sq: int, sk: int, p: float,
     ``heads_global``/``bh_offset`` select a shard-local (b, h) tile of
     the global mask plane (bit-identical to slicing the full mask)."""
     return philox_dropout_mask(batch, n_heads, sq, sk, p, seed, salt,
-                               rounds, interpret=default_interpret(),
-                               heads_global=heads_global,
+                               rounds, heads_global=heads_global,
                                bh_offset=bh_offset)
 
 
@@ -70,7 +67,7 @@ def fused_qkv_gemm_rng(x: jnp.ndarray, w_qkv: jnp.ndarray, *,
         x, w_qkv, mask_batch=mask_batch, mask_heads=mask_heads,
         mask_sq=mask_sq, mask_sk=mask_sk, p=p, seed=seed, salt=salt,
         rounds=rounds, block_m=block_m, block_n=block_n, block_k=block_k,
-        mask_block_cols=mask_block_cols, interpret=default_interpret(),
+        mask_block_cols=mask_block_cols,
         heads_global=heads_global, bh_offset=bh_offset)
 
 
@@ -92,7 +89,7 @@ def fused_gemm_rng_grouped(a: jnp.ndarray, b: jnp.ndarray, *,
         a, b, mask_batch=mask_batch, mask_heads=mask_heads,
         mask_sq=mask_sq, mask_sk=mask_sk, p=p, seed=seed, salt=salt,
         rounds=rounds, block_m=block_m, block_n=block_n, block_k=block_k,
-        mask_block_cols=mask_block_cols, interpret=default_interpret(),
+        mask_block_cols=mask_block_cols,
         heads_global=heads_global, bh_offset=bh_offset)
 
 
@@ -113,7 +110,7 @@ def fused_gemm_rng_grouped_fp8(a: jnp.ndarray, b: jnp.ndarray, *,
         a, b, mask_batch=mask_batch, mask_heads=mask_heads,
         mask_sq=mask_sq, mask_sk=mask_sk, p=p, seed=seed, salt=salt,
         rounds=rounds, block_m=block_m, block_n=block_n, block_k=block_k,
-        mask_block_cols=mask_block_cols, interpret=default_interpret(),
+        mask_block_cols=mask_block_cols,
         heads_global=heads_global, bh_offset=bh_offset)
 
 
@@ -135,5 +132,5 @@ def fused_gemm_rng_fp8(x: jnp.ndarray, w: jnp.ndarray, *,
         x, w, mask_batch=mask_batch, mask_heads=mask_heads,
         mask_sq=mask_sq, mask_sk=mask_sk, p=p, seed=seed, salt=salt,
         rounds=rounds, block_m=block_m, block_n=block_n, block_k=block_k,
-        mask_block_cols=mask_block_cols, interpret=default_interpret(),
+        mask_block_cols=mask_block_cols,
         heads_global=heads_global, bh_offset=bh_offset)

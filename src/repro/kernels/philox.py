@@ -17,12 +17,14 @@ Grid: (B*H, SQ32 // rows32_blk, SK // bk). Each step emits a
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.philox_common import (
     global_bh,
     packed_tile_from_counters,
@@ -69,6 +71,7 @@ def _philox_dropout_mask(sd, *, batch: int, n_heads: int, sq: int, sk: int,
             _philox_kernel, rows32_blk=rows32_blk, bk=bk,
             threshold=thr, rounds=rounds, heads_local=n_heads,
             heads_global=heads_global),
+        name="philox_mask",
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec(
@@ -84,7 +87,8 @@ def philox_dropout_mask(batch: int, n_heads: int, sq: int, sk: int,
                         p: float, seed, salt=0,
                         rounds: int = 7,
                         rows32_blk: int = DEFAULT_ROWS32_BLK,
-                        bk: int = DEFAULT_BK, interpret: bool = True,
+                        bk: int = DEFAULT_BK,
+                        interpret: Optional[bool] = None,
                         heads_global: int = 0,
                         bh_offset=0) -> jnp.ndarray:
     """Packed keep-mask (B, H, SQ//32, SK) uint32 from the canonical
@@ -102,5 +106,6 @@ def philox_dropout_mask(batch: int, n_heads: int, sq: int, sk: int,
     return _philox_dropout_mask(
         seed_salt_smem(seed, salt, bh_offset), batch=batch,
         n_heads=n_heads, sq=sq, sk=sk, p=p, rounds=rounds,
-        rows32_blk=rows32_blk, bk=bk, interpret=interpret,
+        rows32_blk=rows32_blk, bk=bk,
+        interpret=resolve_interpret(interpret),
         heads_global=heads_global or n_heads)

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -255,7 +256,7 @@ def pack_bits_q32(bits: jnp.ndarray) -> jnp.ndarray:
     assert bq % 32 == 0
     b = bits.reshape(bq // 32, 32, bk).astype(jnp.uint32)
     shifts = _default_iota((bq // 32, 32, bk), 1)
-    return jnp.sum(b << shifts, axis=1, dtype=jnp.uint32)
+    return _or_disjoint(b << shifts, axis=1)
 
 
 def unpack_bits_q32(packed: jnp.ndarray, bq: int) -> jnp.ndarray:
@@ -320,9 +321,18 @@ def packed_rows_tile(r_start, k_start, sq32: int, salt, k0, k1, threshold,
     for w, word in enumerate((w0, w1, w2, w3)):
         bits = (word >= thr).astype(jnp.uint32).reshape(rows, 8, bk)
         shifts = iota_fn((rows, 8, bk), 1) * np.uint32(4) + np.uint32(w)
-        contrib = jnp.sum(bits << shifts, axis=1, dtype=jnp.uint32)
+        contrib = _or_disjoint(bits << shifts, axis=1)
         packed = contrib if packed is None else packed | contrib
     return packed
+
+
+def _or_disjoint(words: jnp.ndarray, axis: int) -> jnp.ndarray:
+    """OR-reduce uint32 words whose set bits are disjoint along ``axis``.
+    Disjoint bits never carry, so the OR equals the sum; the sum runs in
+    int32 (wrapping) because Mosaic implements no unsigned reductions."""
+    total = jnp.sum(jax.lax.bitcast_convert_type(words, jnp.int32),
+                    axis=axis)
+    return jax.lax.bitcast_convert_type(total, jnp.uint32)
 
 
 def _default_iota(shape, dimension: int) -> jnp.ndarray:

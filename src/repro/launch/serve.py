@@ -48,8 +48,10 @@ def verify_replay_demo(cfg, sched: DropoutSchedule, batch: int,
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serve import ServeConfig, ServeEngine
 
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")

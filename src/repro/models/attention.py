@@ -7,12 +7,12 @@ fused gemm_rng kernel realizes the same site physically (MXU ∥ VPU).
 """
 from __future__ import annotations
 
+import logging
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.config.base import (
     CARRIED_DROPOUT_SITES,
     AttentionKind,
@@ -22,6 +22,8 @@ from repro.core.attention import attention_decode, attention_xla
 from repro.core.overlap import DropoutPlan
 from repro.distributed.sharding import constrain
 from repro.models.layers import apply_rope, dense_init, rms_head_norm
+
+log = logging.getLogger(__name__)
 
 
 def attn_init(key, cfg: ModelConfig) -> Dict[str, Any]:
@@ -184,7 +186,12 @@ def attn_apply(p, x, cfg: ModelConfig, *, kind: AttentionKind,
             packed = plan.precompute_mask(b, cfg.n_heads, s, s,
                                           layer_idx, step)
 
-    if impl == "pallas" and _pallas_ok(plan, policy, cfg, s):
+    pallas = impl == "pallas" and _pallas_ok(plan, policy, cfg, s)
+    if impl == "pallas" and not pallas:
+        log.warning("attn_impl='pallas' refused for seq=%d heads=%d "
+                    "(fused-mode dropout, seq %% 128, or a kv-indivisible "
+                    "head mesh): running XLA attention", s, cfg.n_heads)
+    if pallas:
         out = _attn_pallas_sharded(
             q, k, v, packed, plan, local, policy,
             replay_key=(layer_idx, step) if replay else None)
@@ -193,6 +200,9 @@ def attn_apply(p, x, cfg: ModelConfig, *, kind: AttentionKind,
             # fallback chain replay -> premask -> xla: this runtime
             # cannot replay in-kernel, so regenerate the identical
             # plane and consume it the premask way
+            from repro.core import producer
+            producer.note_realized(producer.HOW_REPLAY, producer.HOW_XLA,
+                                   "attention consumer")
             packed = plan.precompute_mask(b, cfg.n_heads, s, s,
                                           layer_idx, step)
         import jax.numpy as _jnp
@@ -245,7 +255,6 @@ def _attn_pallas_sharded(q, k, v, packed, plan, local, policy,
     (b, h) window offset into the operand (producer.shard_mask_tile),
     so shard-local replay equals the global plane's slice exactly."""
     from jax.sharding import PartitionSpec as P
-    from repro.kernels import default_interpret
     from repro.kernels.flash_attention import flash_attention_mosaic
 
     p_drop = plan.cfg.p if (plan is not None and plan.enabled) else 0.0
@@ -256,7 +265,6 @@ def _attn_pallas_sharded(q, k, v, packed, plan, local, policy,
     else:
         mode = "none"
     rounds = plan.cfg.philox_rounds if plan is not None else 7
-    interp = default_interpret()
     n_heads = q.shape[1]
 
     def body(q_, k_, v_, m_, heads_global=0):
@@ -267,7 +275,7 @@ def _attn_pallas_sharded(q, k, v, packed, plan, local, policy,
         bq, bk = attn_flash_blocks(q_.shape[2], k_.shape[2])
         return flash_attention_mosaic(
             q_, k_, v_, m_, True, local, p_drop, mode, 0, 0, rounds,
-            bq, bk, interp, heads_global)
+            bq, bk, None, heads_global)
 
     if mode == "replay":
         from repro.kernels.philox_common import seed_salt_smem
@@ -299,14 +307,14 @@ def _attn_pallas_sharded(q, k, v, packed, plan, local, policy,
                 shard, bsz, n_heads, sq, sk)
             return body(q_, k_, v_, m_.at[3].set(off), hg)
 
-        return shard_map(
+        return jax.shard_map(
             rbody, mesh=mesh, in_specs=(qs, kvs, kvs, P()),
             out_specs=qs, check_vma=False)(q, k, v, seed_salt)
     if mode == "premask":
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=(qs, kvs, kvs, ms),
             out_specs=qs, check_vma=False)(q, k, v, packed)
-    return shard_map(
+    return jax.shard_map(
         lambda q_, k_, v_: body(q_, k_, v_, None), mesh=mesh,
         in_specs=(qs, kvs, kvs), out_specs=qs,
         check_vma=False)(q, k, v)
@@ -574,7 +582,7 @@ def attention_decode_appended(q, k_cache, v_cache, k_new, v_new, pos,
             k_scale = jnp.ones(k_cache.shape[:3] + (1,), jnp.float32)
             v_scale = k_scale
             # dequant-by-ones keeps one code path; XLA folds it away
-        m, l, num = shard_map(
+        m, l, num = jax.shard_map(
             body, mesh=policy.mesh,
             in_specs=(rep, cache_spec, cache_spec, P(), cache_spec,
                       cache_spec),

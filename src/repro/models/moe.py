@@ -25,7 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.config.base import ModelConfig, MoEConfig
 from repro.distributed.sharding import ShardingPolicy
 from repro.models.layers import dense_init
@@ -73,18 +72,22 @@ def _expert_ffn(recv, w_gate, w_up, w_down, dt, hs=None, sd=None,
         tile = producer.shard_mask_tile(hs.shard, b, nh, sq, sk)
     if hs is not None and hs.site == "ffn_up":
         local_shape, hg, off = tile
-        h_g, mask, _how = producer.grouped_gemm_seeded(
+        h_g, mask, how = producer.grouped_gemm_seeded(
             recv, w_gate.astype(dt), hs.plan, local_shape, sd, sl,
             heads_global=hg, bh_offset=off)
+        producer.note_realized(producer.HOW_GEMM_GROUPED, how,
+                               "MoE expert gate host")
     else:
         h_g = jnp.einsum("ecd,edf->ecf", recv, w_gate.astype(dt))
     h_u = jnp.einsum("ecd,edf->ecf", recv, w_up.astype(dt))
     h = jax.nn.silu(h_g.astype(jnp.float32)).astype(dt) * h_u
     if hs is not None and hs.site == "ffn_down":
         local_shape, hg, off = tile
-        out, mask, _how = producer.grouped_gemm_seeded(
+        out, mask, how = producer.grouped_gemm_seeded(
             h, w_down.astype(dt), hs.plan, local_shape, sd, sl,
             heads_global=hg, bh_offset=off)
+        producer.note_realized(producer.HOW_GEMM_GROUPED, how,
+                               "MoE expert down host")
     else:
         out = jnp.einsum("ecf,efd->ecd", h, w_down.astype(dt))
     return out, mask
@@ -377,7 +380,7 @@ def moe_apply(params: Dict[str, Any], x: jnp.ndarray, cfg: ModelConfig,
     def _run(body, tok_spec, in_specs):
         out_specs = ((tok_spec, P()) if hs is None
                      else (tok_spec, P(), mask_spec))
-        out = shard_map(
+        out = jax.shard_map(
             body, mesh=mesh, in_specs=in_specs + rng_specs,
             out_specs=out_specs, check_vma=False,
         )(x2d, params["router"], params["w_gate"], params["w_up"],

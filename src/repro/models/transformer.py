@@ -14,7 +14,6 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.config.base import AttentionKind, FFNKind, ModelConfig
 from repro.core.overlap import DropoutPlan
 from repro.distributed.sharding import ShardingPolicy, constrain
@@ -525,7 +524,7 @@ def _token_column_write(cache_arr, tok, slot, policy, cfg):
         val = jnp.where(hit, t.astype(c.dtype), cur)
         return jax.lax.dynamic_update_slice_in_dim(c, val, loc, axis=3)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(cache_spec, tok_spec, P()),
         out_specs=cache_spec, check_vma=False,
     )(cache_arr, tok, slot.astype(jnp.int32))

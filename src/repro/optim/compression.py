@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 
 def quantize_int8(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     xf = x.astype(jnp.float32)
@@ -73,9 +71,9 @@ def compressed_allreduce(stacked: jnp.ndarray, residual: jnp.ndarray,
         out, new_r = compressed_psum(xs[0], axis_name, rs[0])
         return out[None], new_r[None]
 
-    return shard_map(body, mesh=mesh, in_specs=(spec, spec),
-                     out_specs=(spec, spec), check_vma=False
-                     )(stacked, residual)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec),
+                         out_specs=(spec, spec), check_vma=False
+                         )(stacked, residual)
 
 
 def residual_init(grads_like) -> Any:

@@ -257,6 +257,7 @@ from repro.core.overlap import plan_from_config
 from repro.data import batch_for_step
 from repro.distributed.sharding import ShardingPolicy, use_policy
 from repro.kernels.ref import philox_mask_ref
+from repro.launch.mesh import auto_mesh
 from repro.kernels.philox_common import shard_plane_windows
 from repro.train.loop import (compile_run_schedule, init_train_state,
     make_train_step)
@@ -280,7 +281,7 @@ def batch_fn(step):
     x, y = batch_for_step(cfg, shape, step)
     return jnp.asarray(x), jnp.asarray(y)
 
-mesh_model = jax.make_mesh((2,), ("model",))
+mesh_model = auto_mesh((2,), ("model",))
 policy = ShardingPolicy(mesh_model)
 plan = plan_from_config(run.dropout)
 
@@ -291,8 +292,11 @@ want = philox_mask_ref(B, cfg.n_heads, S, S, P_,
                        int(plan.step_seed(7)), int(plan.salt(1)))
 x2d = jax.random.normal(jax.random.PRNGKey(0), (B * S, 64))
 w = jax.random.normal(jax.random.PRNGKey(1), (64, 192))
-y_ref, _, _ = producer.gemm_with_mask(x2d, w, plan,
-                                      (B, cfg.n_heads, S, S), 1, 7)
+# XLA's CPU dot does not keep its bits when N is split, so the sharded
+# GEMM is held bitwise to the same kernel run on each shard's N-slice
+y_ref = np.concatenate([np.asarray(producer.gemm_with_mask(
+    x2d, w[:, c:c + 96], plan, (B, cfg.n_heads, S, S), 1, 7)[0])
+    for c in (0, 96)], axis=1)
 y, mask, how = producer.gemm_with_mask(
     x2d, w, plan, (B, cfg.n_heads, S, S), 1, 7,
     how=producer.HOW_GEMM, policy=policy)

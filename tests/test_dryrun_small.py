@@ -18,12 +18,13 @@ from jax.sharding import PartitionSpec as P
 sys.path.insert(0, "src")
 import repro.launch.dryrun as dr
 from repro.launch import mesh as mesh_mod
+from repro.launch.mesh import auto_mesh
 
 # monkeypatch the production mesh down to host scale
 def small_mesh(*, multi_pod=False):
     if multi_pod:
-        return jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-    return jax.make_mesh((2, 4), ("data", "model"))
+        return auto_mesh((2, 2, 2), ("pod", "data", "model"))
+    return auto_mesh((2, 4), ("data", "model"))
 
 dr.make_production_mesh = small_mesh
 
@@ -87,18 +88,19 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import Checkpointer
 from repro.config import get_arch
 from repro.train.loop import init_train_state
+from repro.launch.mesh import auto_mesh
 
 cfg = get_arch("yi-6b", reduced=True)
 state = init_train_state(jax.random.PRNGKey(0), cfg)
 
-mesh4 = jax.make_mesh((4,), ("data",))
+mesh4 = auto_mesh((4,), ("data",))
 sh4 = NamedSharding(mesh4, P())
 state = jax.tree.map(lambda a: jax.device_put(a, sh4), state)
 ck = Checkpointer(r"%s", async_save=False)
 ck.save(3, state)
 
 # restore onto a DIFFERENT mesh (2 of the 4 devices)
-mesh2 = jax.make_mesh((2,), ("data",), devices=jax.devices()[:2])
+mesh2 = auto_mesh((2,), ("data",), devices=jax.devices()[:2])
 sh2 = NamedSharding(mesh2, P())
 shardings = jax.tree.map(lambda a: sh2, state)
 restored = ck.restore(3, state, shardings=shardings)

@@ -485,6 +485,7 @@ from repro.core.schedule import compile_schedule
 from repro.distributed.sharding import ShardingPolicy, use_policy
 from repro.kernels.ref import philox_mask_ref
 from repro.models import moe as moe_mod
+from repro.launch.mesh import auto_mesh
 from repro.models.transformer import Runtime, forward, model_init
 
 P_, SEED_ = 0.25, 5
@@ -499,7 +500,7 @@ tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 128), 0,
                             cfg.vocab_size)
 plan = plan_from_config(DropoutPlanConfig(mode="overlap", p=P_,
                                           seed=SEED_, site="ffn_up"))
-policy = ShardingPolicy(jax.make_mesh((2,), ("data",)))
+policy = ShardingPolicy(auto_mesh((2,), ("data",)))
 
 # 1) schedule: EP mesh keeps the grouped kernel, shard-local, no degrade
 sched = compile_schedule(cfg, plan.cfg, 2, 128, policy=policy,

@@ -19,12 +19,13 @@ from jax.sharding import PartitionSpec as P
 
 from repro.config import get_arch
 from repro.distributed.sharding import ShardingPolicy
+from repro.launch.mesh import auto_mesh
 from repro.models.moe import moe_apply, moe_init
 
 cfg = get_arch("moonshot-v1-16b-a3b", reduced=True)
 # reduced: d_model=64, 8 experts top-3; mesh (data=2, model=4):
 # experts%data==0, experts%model==0, d_ff_expert=96%4==0, d_model%2==0
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = auto_mesh((2, 4), ("data", "model"))
 key = jax.random.PRNGKey(0)
 params = moe_init(key, cfg)
 b, s = 4, 64

@@ -323,6 +323,7 @@ from repro.core.overlap import plan_from_config
 from repro.core.schedule import compile_schedule
 from repro.distributed.sharding import ShardingPolicy, use_policy
 from repro.models.transformer import Runtime, forward, model_init
+from repro.launch.mesh import auto_mesh
 
 P_, SEED_ = 0.25, 5
 cfg = ModelConfig(name="t", family="dense", n_layers=2, d_model=64,
@@ -348,7 +349,7 @@ def run(site, policy, replay):
 # remap from the (4,)-word's bh_offset: shard-local calls must replay
 # GLOBAL-position counters)
 for axes in (("data",), ("model",)):
-    policy = ShardingPolicy(jax.make_mesh((2,), axes))
+    policy = ShardingPolicy(auto_mesh((2,), axes))
     for site in ("qkv", "ffn_up"):
         sched = compile_schedule(cfg, pcfg(site, "auto"), 2, 128,
                                  policy=policy, attn_impl="pallas")

@@ -274,6 +274,7 @@ from repro.core.schedule import compile_schedule
 from repro.distributed.sharding import ShardingPolicy, use_policy
 from repro.kernels.ref import philox_mask_ref
 from repro.models.transformer import Runtime, forward, model_init
+from repro.launch.mesh import auto_mesh
 
 P_, SEED_ = 0.25, 5
 cfg = ModelConfig(name="t", family="dense", n_layers=2, d_model=64,
@@ -307,14 +308,21 @@ want = philox_mask_ref(b, h, s, s, P_, int(plan.step_seed(7)),
 x2d = jax.random.normal(jax.random.PRNGKey(0), (b * s, 64))
 w = jax.random.normal(jax.random.PRNGKey(1), (64, 192))
 y_ref, _, _ = producer.gemm_with_mask(x2d, w, plan, (b, h, s, s), 3, 7)
+# XLA's CPU dot does not keep its bits when N is split, so the model-axis
+# GEMM (each shard owns an N-slice) is held bitwise to the same kernel
+# run on each slice
+y_cols = np.concatenate([np.asarray(producer.gemm_with_mask(
+    x2d, w[:, c:c + 96], plan, (b, h, s, s), 3, 7)[0]) for c in (0, 96)],
+    axis=1)
 for axes in (("data",), ("model",)):
-    policy = ShardingPolicy(jax.make_mesh((2,), axes))
+    policy = ShardingPolicy(auto_mesh((2,), axes))
     y, mask, how = producer.gemm_with_mask(
         x2d, w, plan, (b, h, s, s), 3, 7, how=producer.HOW_GEMM,
         policy=policy)
     assert how == producer.HOW_GEMM, how
     np.testing.assert_array_equal(np.asarray(mask), np.asarray(want))
-    np.testing.assert_array_equal(np.asarray(y), np.asarray(y_ref))
+    np.testing.assert_array_equal(
+        np.asarray(y), y_cols if axes == ("model",) else np.asarray(y_ref))
     m2 = producer.standalone_packed_mask(plan, b, h, s, s, 3, 7,
                                          policy=policy)
     np.testing.assert_array_equal(np.asarray(m2), np.asarray(want))
@@ -323,7 +331,7 @@ for axes in (("data",), ("model",)):
 #    fused kernel (no HOW_XLA degrade) and mark production shard-local
 # 3) model-level: sharded logits == unsharded logits, bitwise, per site
 for axes in (("data",), ("model",)):
-    policy = ShardingPolicy(jax.make_mesh((2,), axes))
+    policy = ShardingPolicy(auto_mesh((2,), axes))
     for site in ("qkv", "prev_gemm", "ffn_up", "ffn_down"):
         sched = compile_schedule(cfg, pcfg(site), 2, 128, policy=policy,
                                  attn_impl="pallas")

@@ -95,8 +95,8 @@ def test_error_feedback_converges():
 
 
 def test_compressed_allreduce_single_rank():
-    """The shard_map form of the compressed DP all-reduce (via the compat
-    shim): on a 1-rank axis the mean-reduced value is the quantization
+    """The shard_map form of the compressed DP all-reduce: on a 1-rank
+    axis the mean-reduced value is the quantization
     round-trip and the residual carries the error."""
     from repro.optim import compressed_allreduce
     x = jnp.asarray(np.random.default_rng(2).standard_normal((1, 64)),
