@@ -33,7 +33,11 @@ import dataclasses
 from typing import Dict, List, Optional, Tuple
 
 from repro.analysis import rules
-from repro.config.base import CARRIED_DROPOUT_SITES, ModelConfig
+from repro.config.base import (
+    CARRIED_DROPOUT_SITES,
+    AttentionKind,
+    ModelConfig,
+)
 from repro.core import producer
 from repro.core.overlap import SALT_ATTN, SALT_EMBED, SALT_RESID
 from repro.core.schedule import DropoutSchedule, HostAssignment
@@ -332,6 +336,93 @@ def schedule_emissions(cfg: ModelConfig, sched: DropoutSchedule
                              and sh.policy_installed and sh.active),
                 cache=cache, dropped=tgt_replay))
     return tuple(out)
+
+
+# --------------------------------------------------------------------------
+# draw counts
+# --------------------------------------------------------------------------
+
+# the kernel (pallas_call name) that draws a materialized emission; "xla"
+# draws it with XLA ops
+DRAW_KERNELS = {producer.HOW_GEMM: "gemm_rng",
+                producer.HOW_GEMM_GROUPED: "gemm_rng_grouped",
+                producer.HOW_STANDALONE: "philox_mask",
+                producer.HOW_XLA: "xla"}
+# the flash kernels that re-derive a replay-planned layer's bits: the
+# forward, and in a train step both backward kernels
+REPLAY_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+
+
+@dataclasses.dataclass(frozen=True)
+class DrawCount:
+    """One producer kernel's Philox keep decisions in a step, summed
+    over every shard's window: ``live`` ones some consumer reads,
+    ``dropped`` ones nothing reads (a run-and-discard host, a tail
+    emission past the last layer)."""
+    kernel: str
+    calls: int                    # calls a step on each device
+    live: int
+    dropped: int
+
+    @property
+    def drawn(self) -> int:
+        return self.live + self.dropped
+
+
+def _replay_tile_runs(block: Block, sk: int, window: int) -> bool:
+    """Whether the causal flash grid runs this replay cell: the kernels
+    skip (and draw nothing for) a tile whose every key lies after its
+    last query, or, with a sliding window, before its first."""
+    _, r0, r1, c0, c1 = block
+    q_lo = (r0 % (sk // 32)) * 32
+    q_hi = q_lo + (r1 - r0) * 32 - 1
+    return c0 <= q_hi and (window <= 0 or c1 - 1 > q_lo - window)
+
+
+def draw_counts(cfg: ModelConfig, sched: DropoutSchedule,
+                train: bool = True) -> Tuple[DrawCount, ...]:
+    """Calls and keep decisions of each producer kernel in one step
+    (``train``: forward and backward, else the forward alone), from the
+    schedule's emissions. A materialized emission draws its whole plane
+    once; a replay-planned layer's bits are drawn again by every flash
+    kernel that re-derives them, over the tiles its causal grid runs."""
+    totals: Dict[str, List[int]] = {}
+    for em in schedule_emissions(cfg, sched):
+        if em.how == producer.HOW_REPLAY:
+            kinds = cfg.layer_kinds()
+            window = (cfg.local_window
+                      if kinds[em.target_layer] == AttentionKind.LOCAL
+                      else 0)
+            blocks = [b for b in em.blocks
+                      if _replay_tile_runs(b, em.sk, window)]
+            kernels = REPLAY_KERNELS if train else REPLAY_KERNELS[:1]
+        else:
+            blocks = em.blocks
+            kernels = (DRAW_KERNELS[em.how],)
+        drawn = 32 * len(em.windows) * sum(
+            (r1 - r0) * (c1 - c0) for _, r0, r1, c0, c1 in blocks)
+        for k in kernels:
+            calls, live, dropped = totals.setdefault(k, [0, 0, 0])
+            totals[k] = [calls + 1, live + (0 if em.dropped else drawn),
+                         dropped + (drawn if em.dropped else 0)]
+    return tuple(DrawCount(k, *v) for k, v in totals.items())
+
+
+def live_draw_share(counts: Tuple[DrawCount, ...]) -> Optional[float]:
+    """Keep decisions some consumer reads over all drawn, or None when
+    nothing draws."""
+    drawn = sum(c.drawn for c in counts)
+    return sum(c.live for c in counts) / drawn if drawn else None
+
+
+def explain_draws(counts: Tuple[DrawCount, ...]) -> str:
+    """One line for the launch log, printed beside the schedule."""
+    if not counts:
+        return "dropout draws a step: none"
+    parts = [f"{c.kernel} {c.calls} calls {c.live / 1e6:.1f}M live "
+             f"{c.dropped / 1e6:.1f}M dropped" for c in counts]
+    return ("dropout draws a step: " + " | ".join(parts)
+            + f" | live share {100 * live_draw_share(counts):.1f}%")
 
 
 # --------------------------------------------------------------------------

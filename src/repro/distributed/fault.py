@@ -110,6 +110,33 @@ class RunnerReport:
     losses: Dict[int, float] = dataclasses.field(default_factory=dict)
 
 
+_collections_named = False
+
+
+def _name_collections() -> None:
+    """Put each of Python's garbage collections in the profiler's host
+    trace as a ``python.gc`` span (a no-op unless a profiler runs), so a
+    host stall in the collector is named beside the runner's spans.
+    Installed once a process."""
+    global _collections_named
+    if _collections_named:
+        return
+    import gc
+
+    from jax.profiler import TraceAnnotation
+    open_spans: List[TraceAnnotation] = []
+
+    def on_collect(phase, info):
+        if phase == "start":
+            open_spans.append(TraceAnnotation("python.gc"))
+            open_spans[-1].__enter__()
+        elif open_spans:
+            open_spans.pop().__exit__(None, None, None)
+
+    gc.callbacks.append(on_collect)
+    _collections_named = True
+
+
 class TrainRunner:
     """Crash-recovering training loop.
 
@@ -151,6 +178,7 @@ class TrainRunner:
         self.schedule = schedule
         self.restarts = 0
         self.failed_saves = 0
+        _name_collections()
 
     def _save(self, step: int) -> None:
         """Checkpoint; a write failure (its own, or the PREVIOUS async
@@ -188,46 +216,65 @@ class TrainRunner:
                       sched=self.schedule)
 
     def run(self, n_steps: int) -> RunnerReport:
+        """Steps up to ``n_steps``. Each step is a profiler step marker
+        ("train", step_num) holding the spans runner.batch, runner.step
+        (the dispatch), runner.wait, runner.loss and runner.checkpoint,
+        or runner.recover after a crash; runner.sync covers the read of
+        the state's step, runner.report the final metrics' reads. The
+        spans cost nothing unless a profiler runs, and share its clock
+        with the device's ops."""
         import jax
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
         from repro.checkpoint.contract import ContractMismatchError
         metrics = {}
         losses: Dict[int, float] = {}
-        step = int(jax.device_get(self.state["step"]))
+        with TraceAnnotation("runner.sync"):
+            step = int(jax.device_get(self.state["step"]))
         while step < n_steps:
-            try:
-                if self.failure_hook is not None:
-                    self.failure_hook(step)
-                x, y = self.batch_fn(step)
-                t0 = time.perf_counter()
-                self.state, metrics = self.step_fn(self.state, x, y)
-                jax.block_until_ready(metrics["loss"])
-                self.straggler.observe(time.perf_counter() - t0)
-                losses[step] = float(metrics["loss"])
-                step += 1
-                if step % self.checkpoint_every == 0:
-                    self._save(step)
-            except ContractMismatchError:
-                raise                     # fail fast: wrong mask bits
-            except Exception:
-                self.restarts += 1
-                if self.restarts > self.max_restarts:
-                    raise
-                # a failed async save surfacing here is NOT the crash
-                # we are recovering from — count it and restore from
-                # the last checkpoint that actually landed
-                self._drain_pending_save()
-                latest = self.ckpt.latest_step()
-                if latest is not None:
-                    self.state = self.ckpt.restore(latest, self.state)
-                    self._verify_contract(latest)
-                    step = latest
-                else:
-                    step = 0
-        self._drain_pending_save()
-        return RunnerReport(
-            steps_completed=step,
-            restarts=self.restarts,
-            straggler_steps=len(self.straggler.flagged),
-            failed_saves=self.failed_saves,
-            final_metrics={k: float(v) for k, v in metrics.items()},
-            losses=losses)
+            with StepTraceAnnotation("train", step_num=step):
+                try:
+                    if self.failure_hook is not None:
+                        self.failure_hook(step)
+                    with TraceAnnotation("runner.batch"):
+                        x, y = self.batch_fn(step)
+                    t0 = time.perf_counter()
+                    with TraceAnnotation("runner.step"):
+                        self.state, metrics = self.step_fn(self.state, x,
+                                                           y)
+                    with TraceAnnotation("runner.wait"):
+                        jax.block_until_ready(metrics["loss"])
+                    self.straggler.observe(time.perf_counter() - t0)
+                    with TraceAnnotation("runner.loss"):
+                        losses[step] = float(metrics["loss"])
+                    step += 1
+                    if step % self.checkpoint_every == 0:
+                        with TraceAnnotation("runner.checkpoint"):
+                            self._save(step)
+                except ContractMismatchError:
+                    raise                     # fail fast: wrong mask bits
+                except Exception:
+                    self.restarts += 1
+                    if self.restarts > self.max_restarts:
+                        raise
+                    with TraceAnnotation("runner.recover"):
+                        # a failed async save surfacing here is NOT the
+                        # crash we are recovering from — count it and
+                        # restore from the last checkpoint that landed
+                        self._drain_pending_save()
+                        latest = self.ckpt.latest_step()
+                        if latest is not None:
+                            self.state = self.ckpt.restore(latest,
+                                                           self.state)
+                            self._verify_contract(latest)
+                            step = latest
+                        else:
+                            step = 0
+        with TraceAnnotation("runner.report"):
+            self._drain_pending_save()
+            return RunnerReport(
+                steps_completed=step,
+                restarts=self.restarts,
+                straggler_steps=len(self.straggler.flagged),
+                failed_saves=self.failed_saves,
+                final_metrics={k: float(v) for k, v in metrics.items()},
+                losses=losses)

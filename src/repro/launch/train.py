@@ -38,6 +38,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.analysis.counters import draw_counts, explain_draws
 from repro.checkpoint import (
     Checkpointer,
     contract_from_schedule,
@@ -132,6 +133,7 @@ def main(argv: Optional[Sequence[str]] = None) -> RunnerReport:
     sched = compile_run_schedule(cfg, run)
     contract = contract_from_schedule(cfg, sched)
     print(sched.explain())
+    print(explain_draws(draw_counts(cfg, sched)))
 
     state = init_train_state(jax.random.PRNGKey(args.seed), cfg)
     ckpt = Checkpointer(args.ckpt_dir)

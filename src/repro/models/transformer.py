@@ -249,19 +249,24 @@ def block_apply(p, x, cfg, rt: Runtime, kind, tag, layer_idx,
     is_attn = kind in (AttentionKind.FULL, AttentionKind.LOCAL)
     ffn_hosts = (emit and is_attn and asg is not None
                  and asg.emit_site in ("ffn_up", "ffn_down"))
-    h = norm_apply(p["norm_mix"], x, cfg)
-    y, mask_next = _mix_forward(
-        p["mix"], h, cfg, rt, kind, layer_idx, mask_in=mask_in,
-        emit_next=emit and is_attn and not ffn_hosts, asg=asg)
-    x = x + y
-    h2 = norm_apply(p["norm_ffn"], x, cfg)
-    if ffn_hosts:
-        b, s = x.shape[0], x.shape[1]
-        f, aux, mask_next = _ffn_forward(
-            p, h2, cfg, rt, tag, layer_idx=layer_idx, asg=asg,
-            mask_shape=(b, cfg.n_heads, s, s))
-    else:
-        f, aux, _ = _ffn_forward(p, h2, cfg, rt, tag)
+    # the scope names are read by the benchmark's device-trace
+    # attribution (bench/scopes.py): keep them stable
+    with jax.named_scope("attn"):
+        h = norm_apply(p["norm_mix"], x, cfg)
+        y, mask_next = _mix_forward(
+            p["mix"], h, cfg, rt, kind, layer_idx, mask_in=mask_in,
+            emit_next=emit and is_attn and not ffn_hosts, asg=asg)
+        x = x + y
+    with jax.named_scope("ffn"):
+        h2 = norm_apply(p["norm_ffn"], x, cfg)
+        if ffn_hosts:
+            b, s = x.shape[0], x.shape[1]
+            f, aux, mask_next = _ffn_forward(
+                p, h2, cfg, rt, tag, layer_idx=layer_idx, asg=asg,
+                mask_shape=(b, cfg.n_heads, s, s))
+        else:
+            f, aux, _ = _ffn_forward(p, h2, cfg, rt, tag)
+        x = x + f
     if emit and not is_attn:
         mask_next = mask_in        # carry rides through mixer-only blocks
     if mask_next is not None and asg is not None:
@@ -271,13 +276,14 @@ def block_apply(p, x, cfg, rt: Runtime, kind, tag, layer_idx,
             # gemm-hosted emission ran for the RNG-under-GEMM overlap
             # only — drop its output here, nothing reaches the carry
             mask_next = None
-    return x + f, aux, mask_next
+    return x, aux, mask_next
 
 
 # --------------------------------------------------------------------------
 # full forward (training)
 # --------------------------------------------------------------------------
 
+@jax.named_scope("embed")
 def embed_inputs(params, cfg: ModelConfig, inputs, rt: Runtime):
     if cfg.frontend == "token":
         x = jnp.take(params["embed"], inputs, axis=0)
@@ -352,64 +358,70 @@ def forward(params, cfg: ModelConfig, rt: Runtime, inputs
         from repro.core import producer
         basg = sched.for_layer(sched.first_consumer)
         b, s = x.shape[0], x.shape[1]
-        mask_buf = producer.standalone_packed_mask(
-            rt.plan, b, cfg.n_heads, s, s, sched.first_consumer, rt.step,
-            use_kernel=basg.how == producer.HOW_STANDALONE,
-            policy=rt.policy if basg.sharded else None)
-    for spec, stack_params in zip(build_stacks(cfg), params["stacks"]):
-        unit_len = len(spec.unit)
-        # static per-unit-position assignments: the scan compiles ONE
-        # body, so the schedule guarantees positional periodicity
-        # within each stack (schedule._check_scan_periodicity)
-        unit_asgs = tuple(
-            sched.for_layer(spec.base + j) if active else None
-            for j in range(unit_len))
+        with jax.named_scope("mask"):
+            mask_buf = producer.standalone_packed_mask(
+                rt.plan, b, cfg.n_heads, s, s, sched.first_consumer,
+                rt.step, use_kernel=basg.how == producer.HOW_STANDALONE,
+                policy=rt.policy if basg.sharded else None)
+    # the scans' own slices of the stacked parameters and their stacked
+    # writes fall in "layers", outside any block's "attn" / "ffn"
+    with jax.named_scope("layers"):
+        for spec, stack_params in zip(build_stacks(cfg),
+                                      params["stacks"]):
+            unit_len = len(spec.unit)
+            # static per-unit-position assignments: the scan compiles ONE
+            # body, so the schedule guarantees positional periodicity
+            # within each stack (schedule._check_scan_periodicity)
+            unit_asgs = tuple(
+                sched.for_layer(spec.base + j) if active else None
+                for j in range(unit_len))
 
-        def unit_apply(x, mask, up, pos, _spec=spec, _ul=unit_len,
-                       _asgs=unit_asgs):
-            aux = jnp.float32(0.0)
-            for j, (kind, tag) in enumerate(_spec.unit):
-                lidx = _spec.base + pos * _ul + j
-                x, a, mask = block_apply(up[f"l{j}"], x, cfg, rt, kind,
-                                         tag, lidx, asg=_asgs[j],
-                                         mask_in=mask,
-                                         emit=carry_mask)
-                aux = aux + a
-            return x, aux, mask
+            def unit_apply(x, mask, up, pos, _spec=spec, _ul=unit_len,
+                           _asgs=unit_asgs):
+                aux = jnp.float32(0.0)
+                for j, (kind, tag) in enumerate(_spec.unit):
+                    lidx = _spec.base + pos * _ul + j
+                    x, a, mask = block_apply(up[f"l{j}"], x, cfg, rt,
+                                             kind, tag, lidx,
+                                             asg=_asgs[j], mask_in=mask,
+                                             emit=carry_mask)
+                    aux = aux + a
+                return x, aux, mask
 
-        if rt.remat == "block":
-            unit_apply = jax.checkpoint(
-                unit_apply,
-                policy=jax.checkpoint_policies.nothing_saveable)
+            if rt.remat == "block":
+                unit_apply = jax.checkpoint(
+                    unit_apply,
+                    policy=jax.checkpoint_policies.nothing_saveable)
 
-        if carry_mask:
-            def body(carry, xs, _ua=unit_apply):
-                xc, aux, mask = carry
-                up, pos = xs
-                xn, a, mask = _ua(xc, mask, up, pos)
-                return (xn, aux + a, mask), None
+            if carry_mask:
+                def body(carry, xs, _ua=unit_apply):
+                    xc, aux, mask = carry
+                    up, pos = xs
+                    xn, a, mask = _ua(xc, mask, up, pos)
+                    return (xn, aux + a, mask), None
 
-            (x, aux_total, mask_buf), _ = jax.lax.scan(
-                body, (x, aux_total, mask_buf),
-                (stack_params, jnp.arange(spec.count)))
-        else:
-            def body(carry, xs, _ua=unit_apply):
-                xc, aux = carry
-                up, pos = xs
-                xn, a, _ = _ua(xc, None, up, pos)
-                return (xn, aux + a), None
+                (x, aux_total, mask_buf), _ = jax.lax.scan(
+                    body, (x, aux_total, mask_buf),
+                    (stack_params, jnp.arange(spec.count)))
+            else:
+                def body(carry, xs, _ua=unit_apply):
+                    xc, aux = carry
+                    up, pos = xs
+                    xn, a, _ = _ua(xc, None, up, pos)
+                    return (xn, aux + a), None
 
-            (x, aux_total), _ = jax.lax.scan(
-                body, (x, aux_total),
-                (stack_params, jnp.arange(spec.count)))
+                (x, aux_total), _ = jax.lax.scan(
+                    body, (x, aux_total),
+                    (stack_params, jnp.arange(spec.count)))
     # the last attention layer's emitted mask (consumer index beyond
     # n_layers) has no consumer — dropped here. The scan compiles ONE
     # body for all iterations, so that final generation cannot be
     # peeled away: carried sites pay one extra B*H*(S/32)*S mask per
     # forward (hidden under the GEMM when fused; cheap but real in the
     # XLA path).
-    x = norm_apply(params["final_norm"], x, cfg)
-    return unembed(params, cfg, x), aux_total
+    with jax.named_scope("unembed"):
+        x = norm_apply(params["final_norm"], x, cfg)
+        return unembed(params, cfg, x), aux_total
 
 
 # --------------------------------------------------------------------------

@@ -120,7 +120,8 @@ def make_train_step(cfg: ModelConfig, run: RunConfig,
                      schedule=sched)
         with use_policy(policy):
             logits, aux = forward(params, cfg, rt, x)
-            ce = cross_entropy(logits, y)
+            with jax.named_scope("loss"):
+                ce = cross_entropy(logits, y)
         loss = ce + AUX_WEIGHT * aux
         return loss, (ce, aux)
 
@@ -152,9 +153,10 @@ def make_train_step(cfg: ModelConfig, run: RunConfig,
         else:
             (loss, (ce, aux)), grads = grad_fn(state["master"], x, y, step)
 
-        master, _, opt, om = adamw_update(
-            grads, state["opt"], state["master"], run.train.optimizer,
-            step, compute_dtype)
+        with jax.named_scope("optimizer"):
+            master, _, opt, om = adamw_update(
+                grads, state["opt"], state["master"], run.train.optimizer,
+                step, compute_dtype)
         new_state = {"master": master, "opt": opt, "step": step + 1}
         metrics = {"loss": loss, "ce": ce, "aux": aux, **om}
         return new_state, metrics
