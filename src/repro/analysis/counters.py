@@ -202,22 +202,18 @@ def _standalone_blocks(cfg: ModelConfig, sched: DropoutSchedule
 
 
 def _replay_blocks(cfg: ModelConfig, sched: DropoutSchedule,
-                   block_q: Optional[int] = None,
-                   block_k: Optional[int] = None
-                   ) -> Tuple[Tuple[Block, ...], int]:
+                   window: int) -> Tuple[Tuple[Block, ...], int]:
     """The flash-attention consumer's replay grid: one in-register
     tile_keep_mask derivation per (bh, q-block, k-block) kernel cell,
     each covering (block_q // 32) packed rows x block_k cols of the
-    local plane. The default blocks resolve through the SAME tuned-table
-    hook models/attention uses (128x128 with no table installed), so
-    the verified replay grid is always the executed kernel grid. Proving
-    this grid exactly tiles the plane is the replay analogue of proving
-    a producer's emission grid double-draws nothing."""
+    local plane. The blocks resolve through the SAME hook
+    models/attention uses (the tuned table, else the shape rule, for
+    the layer's sliding ``window``), so the verified replay grid is
+    always the executed kernel grid. Proving this grid exactly tiles the
+    plane is the replay analogue of proving a producer's emission grid
+    double-draws nothing."""
     seq = sched.seq
-    if block_q is None or block_k is None:
-        dq, dk = producer.attn_flash_blocks(seq, seq)
-        block_q = dq if block_q is None else block_q
-        block_k = dk if block_k is None else block_k
+    block_q, block_k = producer.attn_flash_blocks(seq, seq, window)
     sh = sched.shard
     shard_local = sh.policy_installed and sh.active
     b_loc = sched.batch // sh.batch_shards if shard_local else sched.batch
@@ -239,15 +235,24 @@ def _replay_blocks(cfg: ModelConfig, sched: DropoutSchedule,
     return tuple(blocks), b_loc * h_loc * sq32
 
 
+def _layer_window(cfg: ModelConfig, layer: int) -> int:
+    """The sliding window of an attention layer, 0 for a full one."""
+    return (cfg.local_window
+            if cfg.layer_kinds()[layer] == AttentionKind.LOCAL else 0)
+
+
 def _emission(cfg: ModelConfig, sched: DropoutSchedule, *,
               producer_layer: int, target_layer: int, site: str,
               how: str, shard_local: bool,
               cache: Dict, dropped: bool = False) -> MaskEmission:
     """Resolve one planned emission to counter space. ``cache`` shares
     block tuples across the (periodic) layers of one schedule."""
+    window = (_layer_window(cfg, target_layer)
+              if how == producer.HOW_REPLAY else 0)
     key = (site, how,
            cfg.moe is not None
-           and max(producer_layer, 0) >= cfg.moe.first_dense_layers)
+           and max(producer_layer, 0) >= cfg.moe.first_dense_layers,
+           window)
     if key not in cache:
         if how == producer.HOW_GEMM:
             blocks, rows = _fused_blocks(cfg, sched, site,
@@ -260,7 +265,7 @@ def _emission(cfg: ModelConfig, sched: DropoutSchedule, *,
         elif how == producer.HOW_STANDALONE:
             blocks, rows = _standalone_blocks(cfg, sched)
         elif how == producer.HOW_REPLAY:
-            blocks, rows = _replay_blocks(cfg, sched)
+            blocks, rows = _replay_blocks(cfg, sched, window)
         else:                      # HOW_XLA: one monolithic draw
             sh = sched.shard
             shard_ok = sh.policy_installed and sh.active and shard_local
@@ -358,11 +363,16 @@ class DrawCount:
     """One producer kernel's Philox keep decisions in a step, summed
     over every shard's window: ``live`` ones some consumer reads,
     ``dropped`` ones nothing reads (a run-and-discard host, a tail
-    emission past the last layer)."""
+    emission past the last layer). A flash kernel also carries its grid:
+    the (block_q, block_k) tiles it ran, and its grid steps a step on
+    each device, run and skipped by the causal (and window) skip."""
     kernel: str
     calls: int                    # calls a step on each device
     live: int
     dropped: int
+    tiles: Tuple[Tuple[int, int], ...] = ()
+    steps_run: int = 0
+    steps_skipped: int = 0
 
     @property
     def drawn(self) -> int:
@@ -386,26 +396,31 @@ def draw_counts(cfg: ModelConfig, sched: DropoutSchedule,
     schedule's emissions. A materialized emission draws its whole plane
     once; a replay-planned layer's bits are drawn again by every flash
     kernel that re-derives them, over the tiles its causal grid runs."""
-    totals: Dict[str, List[int]] = {}
+    totals: Dict[str, DrawCount] = {}
     for em in schedule_emissions(cfg, sched):
+        tiles, steps_run, steps_skipped = (), 0, 0
         if em.how == producer.HOW_REPLAY:
-            kinds = cfg.layer_kinds()
-            window = (cfg.local_window
-                      if kinds[em.target_layer] == AttentionKind.LOCAL
-                      else 0)
+            window = _layer_window(cfg, em.target_layer)
             blocks = [b for b in em.blocks
                       if _replay_tile_runs(b, em.sk, window)]
             kernels = REPLAY_KERNELS if train else REPLAY_KERNELS[:1]
+            _, r0, r1, c0, c1 = em.blocks[0]
+            tiles = ((32 * (r1 - r0), c1 - c0),)
+            steps_run, steps_skipped = (len(blocks),
+                                        len(em.blocks) - len(blocks))
         else:
             blocks = em.blocks
             kernels = (DRAW_KERNELS[em.how],)
         drawn = 32 * len(em.windows) * sum(
             (r1 - r0) * (c1 - c0) for _, r0, r1, c0, c1 in blocks)
         for k in kernels:
-            calls, live, dropped = totals.setdefault(k, [0, 0, 0])
-            totals[k] = [calls + 1, live + (0 if em.dropped else drawn),
-                         dropped + (drawn if em.dropped else 0)]
-    return tuple(DrawCount(k, *v) for k, v in totals.items())
+            c = totals.get(k, DrawCount(k, 0, 0, 0))
+            totals[k] = DrawCount(
+                k, c.calls + 1, c.live + (0 if em.dropped else drawn),
+                c.dropped + (drawn if em.dropped else 0),
+                tuple(sorted(set(c.tiles + tiles))),
+                c.steps_run + steps_run, c.steps_skipped + steps_skipped)
+    return tuple(totals.values())
 
 
 def live_draw_share(counts: Tuple[DrawCount, ...]) -> Optional[float]:
@@ -415,12 +430,21 @@ def live_draw_share(counts: Tuple[DrawCount, ...]) -> Optional[float]:
     return sum(c.live for c in counts) / drawn if drawn else None
 
 
+def _explain_grid(c: DrawCount) -> str:
+    if not c.tiles:
+        return ""
+    tiles = ",".join(f"{bq}x{bk}" for bq, bk in c.tiles)
+    return (f" tile {tiles} grid steps {c.steps_run} run "
+            f"{c.steps_skipped} skipped")
+
+
 def explain_draws(counts: Tuple[DrawCount, ...]) -> str:
     """One line for the launch log, printed beside the schedule."""
     if not counts:
         return "dropout draws a step: none"
     parts = [f"{c.kernel} {c.calls} calls {c.live / 1e6:.1f}M live "
-             f"{c.dropped / 1e6:.1f}M dropped" for c in counts]
+             f"{c.dropped / 1e6:.1f}M dropped" + _explain_grid(c)
+             for c in counts]
     return ("dropout draws a step: " + " | ".join(parts)
             + f" | live share {100 * live_draw_share(counts):.1f}%")
 

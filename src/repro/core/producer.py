@@ -93,6 +93,10 @@ _BLOCK_K_CAP = 512
 _MASK_COLS_CAP = 2048
 # the standalone philox kernel's column block
 _PHILOX_COLS_CAP = 512
+# flash-attention tile sides, largest first: 512 x 512 ran all three
+# flash kernels fastest at (S, D) = (1536, 64) and (4096, 128) on a TPU
+# v5e, where each grid step costs about 0.35 us whatever it computes
+_FLASH_TILES = (512, 256, 128)
 
 _DTYPE_BYTES = {"f32": 4, "bf16": 2, "fp8": 1}
 
@@ -130,15 +134,30 @@ def mask_cols_cap(sq: int, sk: int) -> int:
     return _MASK_COLS_CAP
 
 
-def attn_flash_blocks(sq: int, sk: int) -> Tuple[int, int]:
-    """The flash-attention (block_q, block_k) for this plane: the active
-    tuned table's (bit-identity-proven) choice, else 128x128. Both the
+def _flash_tile(n: int, local_window: int) -> int:
+    """The largest tile side that divides ``n`` and leaves at least two
+    blocks along it, so the causal skip still has tiles to drop, and that
+    a sliding window does not undercut, so window skipping keeps its
+    granularity; 128 where none does (every n <= 256)."""
+    for t in _FLASH_TILES:
+        if n % t == 0 and 2 * t <= n and (local_window <= 0
+                                           or t <= local_window):
+            return t
+    return _FLASH_TILES[-1]
+
+
+def attn_flash_blocks(sq: int, sk: int,
+                      local_window: int = 0) -> Tuple[int, int]:
+    """The flash-attention (block_q, block_k) for this plane, shared by
+    the fwd, dq and dkv kernels: the active tuned table's
+    (bit-identity-proven) choice, else chosen from the shape. Both the
     executing kernel call (models/attention) and the verifier's replay
     grid (analysis/counters._replay_blocks) resolve through here."""
+    blocks = (_flash_tile(sq, local_window), _flash_tile(sk, local_window))
     t = _tuned_tables()
     if t is not None:
-        return t.active_flash_blocks(sq, sk)
-    return (128, 128)
+        return t.active_flash_blocks(sq, sk, default=blocks)
+    return blocks
 
 
 def pick_gemm_blocks(m: int, n: int, k: int
@@ -217,9 +236,10 @@ def replay_unsupported_reason(plan: DropoutPlan, sq: int, sk: int,
     counters in-register (mode="replay") — None when it can. Replay is
     exact only when the consumer reconstructs the producer's counter
     tiling bit-for-bit: the 32-bit Philox scheme (8-bit planes are an
-    XLA-only byte layout with no tile counters) on the flash kernels'
-    128x128 grid. The runtime fallback chain on a refused cell is
-    replay -> premask -> xla (models/attention.attn_apply)."""
+    XLA-only byte layout with no tile counters) on a flash grid of
+    128-multiple tiles (``attn_flash_blocks``). The runtime fallback
+    chain on a refused cell is replay -> premask -> xla
+    (models/attention.attn_apply)."""
     if plan.cfg.attn_replay == "off":
         return "disabled by plan (attn_replay=off)"
     if attn_impl != "pallas":

@@ -26,7 +26,10 @@
 
 Tiling: grid (B, H, SQ/bq, SK/bk), k-minor so the online-softmax running
 stats (m, l, acc) live in VMEM scratch across the k sweep. Causal and
-sliding-window blocks that are fully masked are skipped with pl.when.
+sliding-window blocks that are fully masked are skipped with pl.when,
+and their index maps repeat the nearest running block, so a skipped
+step fetches nothing. The tile is the caller's: the model path takes it
+from ``core/producer.attn_flash_blocks``.
 Dropout semantics match ref.attention_ref bit-exactly: softmax normalizer l
 accumulates *undropped* probabilities; the keep-mask zeroes the numerator
 contributions; the 1/(1-p) rescale is applied once at finalization.
@@ -173,6 +176,35 @@ def _flash_kernel(*refs, bq: int, bk: int, d: int, n_heads: int,
             lse_ref[...] = lse.T[:1][None, None]
 
 
+def run_kv_block(qi, ki, *, bq: int, bk: int, q_offset: int,
+                 local_window: int, nk: int):
+    """The key block grid step (qi, ki) of a causal grid reads: ki where
+    the tile runs, else the nearest block that runs, so a skipped step
+    repeats its neighbour's block index and starts no k/v DMA. Shared by
+    the fwd and dq passes (grid (B, H, SQ/bq, SK/bk))."""
+    last = jax.lax.div(qi * bq + (bq - 1 + q_offset), bk)
+    ki = jnp.minimum(ki, jnp.minimum(last, nk - 1))
+    if local_window > 0:
+        first = jax.lax.div(
+            jnp.maximum(qi * bq + (q_offset - local_window + 1), 0), bk)
+        ki = jnp.maximum(ki, first)
+    return ki
+
+
+def run_q_block(ki, qi, *, bq: int, bk: int, q_offset: int,
+                local_window: int, nq: int):
+    """The query block grid step (ki, qi) of the dkv pass's causal grid
+    (B, H, SK/bk, SQ/bq) reads, by the rule of ``run_kv_block``."""
+    first = jax.lax.div(jnp.maximum(ki * bk - q_offset, 0), bq)
+    qi = jnp.maximum(qi, jnp.minimum(first, nq - 1))
+    if local_window > 0:
+        last = jax.lax.div(
+            jnp.maximum(ki * bk + (bk - 2 - q_offset + local_window), 0),
+            bq)
+        qi = jnp.minimum(qi, jnp.minimum(last, nq - 1))
+    return qi
+
+
 def _check_premask(mask_packed, batch, n_heads, sq, sk):
     """Fail fast on a mis-packed premask plane (the alternative is an
     opaque Pallas grid/BlockSpec error deep inside pallas_call)."""
@@ -273,10 +305,17 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     k0, k1 = seed_to_key(seed)
     grid = (batch, n_heads, sq // bq, sk // bk)
     group = n_heads // kv_heads
+    if causal:
+        kv_block = functools.partial(
+            run_kv_block, bq=bq, bk=bk, q_offset=sk - sq,
+            local_window=int(local_window), nk=sk // bk)
+    else:
+        kv_block = lambda qi, ki: ki
 
     q_spec = pl.BlockSpec((1, 1, bq, d), lambda b, h, qi, ki: (b, h, qi, 0))
-    kv_spec = pl.BlockSpec((1, 1, bk, d),
-                           lambda b, h, qi, ki: (b, h // group, ki, 0))
+    kv_spec = pl.BlockSpec(
+        (1, 1, bk, d),
+        lambda b, h, qi, ki: (b, h // group, kv_block(qi, ki), 0))
     o_spec = pl.BlockSpec((1, 1, bq, d), lambda b, h, qi, ki: (b, h, qi, 0))
     in_specs = [q_spec, kv_spec, kv_spec]
     args = [q, k, v]
@@ -284,8 +323,9 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         # a (bq//32, bk) slab of (SQ//32, SK) is not (8, 128)-aligned for
         # bq = 128; split SQ//32 into (SQ//bq, bq//32) so the block's
         # second-minor dim spans its whole array dim (same words)
-        in_specs.append(pl.BlockSpec((1, 1, 1, bq // 32, bk),
-                                     lambda b, h, qi, ki: (b, h, qi, 0, ki)))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, 1, bq // 32, bk),
+            lambda b, h, qi, ki: (b, h, qi, 0, kv_block(qi, ki))))
         args.append(premask_blocks(mask_packed, bq))
     elif mode == "replay":
         # the whole dropout state: 16 bytes of SMEM, not a q*k plane

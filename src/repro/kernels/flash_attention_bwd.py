@@ -33,7 +33,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.backend import resolve_interpret
-from repro.kernels.flash_attention import premask_blocks
+from repro.kernels.flash_attention import (
+    premask_blocks,
+    run_kv_block,
+    run_q_block,
+)
 from repro.kernels.philox_common import (
     global_bh,
     seed_salt_smem,
@@ -252,19 +256,29 @@ def flash_attention_bwd(q, k, v, o, lse, do,
     if mode == "premask":
         mask_packed = premask_blocks(mask_packed, bq)
 
+    # a skipped causal tile reads the block its nearest running
+    # neighbour reads, so it starts no DMA (dq: k/v, dkv: q-side rows)
+    if causal:
+        clamp = dict(bq=bq, bk=bk, q_offset=sk - sq,
+                     local_window=int(local_window))
+        kblk = functools.partial(run_kv_block, nk=sk // bk, **clamp)
+        qblk = functools.partial(run_q_block, nq=sq // bq, **clamp)
+    else:
+        kblk = qblk = lambda i, j: j
     q_spec = pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0))
-    kq_spec = pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, j, 0))
-    kv_spec = pl.BlockSpec((1, 1, bk, d),
-                           lambda b, h, i, j: (b, h // group, j, 0))
+    kq_spec = pl.BlockSpec((1, 1, bq, d),
+                           lambda b, h, i, j: (b, h, qblk(i, j), 0))
+    kv_spec = pl.BlockSpec(
+        (1, 1, bk, d), lambda b, h, i, j: (b, h // group, kblk(i, j), 0))
     kvk_spec = pl.BlockSpec((1, 1, bk, d),
                             lambda b, h, i, j: (b, h // group, i, 0))
     row_spec = pl.BlockSpec((1, 1, 1, bq), lambda b, h, i, j: (b, h, 0, i))
     rowq_spec = pl.BlockSpec((1, 1, 1, bq),
-                             lambda b, h, i, j: (b, h, 0, j))
+                             lambda b, h, i, j: (b, h, 0, qblk(i, j)))
     mask_spec = pl.BlockSpec((1, 1, 1, bq // 32, bk),
-                             lambda b, h, i, j: (b, h, i, 0, j))
+                             lambda b, h, i, j: (b, h, i, 0, kblk(i, j)))
     maskk_spec = pl.BlockSpec((1, 1, 1, bq // 32, bk),
-                              lambda b, h, i, j: (b, h, j, 0, i))
+                              lambda b, h, i, j: (b, h, qblk(i, j), 0, i))
 
     # ---- dq pass: grid (B, H, nq, nk) --------------------------------
     in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]

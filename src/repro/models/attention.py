@@ -268,11 +268,11 @@ def _attn_pallas_sharded(q, k, v, packed, plan, local, policy,
     n_heads = q.shape[1]
 
     def body(q_, k_, v_, m_, heads_global=0):
-        # block sizes resolve through the tuned-table hook (128x128 with
-        # no table); analysis/counters._replay_blocks uses the same hook,
-        # so the verified replay grid is the executed grid
+        # block sizes resolve through the tuned-table hook (else a rule
+        # over the shape); analysis/counters._replay_blocks uses the
+        # same hook, so the verified replay grid is the executed grid
         from repro.core.producer import attn_flash_blocks
-        bq, bk = attn_flash_blocks(q_.shape[2], k_.shape[2])
+        bq, bk = attn_flash_blocks(q_.shape[2], k_.shape[2], local)
         return flash_attention_mosaic(
             q_, k_, v_, m_, True, local, p_drop, mode, 0, 0, rounds,
             bq, bk, None, heads_global)

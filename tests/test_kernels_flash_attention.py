@@ -103,3 +103,74 @@ def test_gradients_match_ref(rng_key):
     for a, b in zip(gk, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-4)
+
+
+# ------------------------------------------- tiles chosen by the shape
+
+def test_flash_blocks_rule():
+    """The tile rule: the largest of 512 / 256 / 128 that divides the
+    length, leaves two blocks along it, and fits a sliding window."""
+    from repro.core.producer import attn_flash_blocks
+    assert attn_flash_blocks(256, 256) == (128, 128)
+    assert attn_flash_blocks(1024, 1024) == (512, 512)
+    assert attn_flash_blocks(1536, 1536) == (512, 512)
+    assert attn_flash_blocks(4096, 4096) == (512, 512)
+    assert attn_flash_blocks(512, 512) == (256, 256)
+    assert attn_flash_blocks(640, 640) == (128, 128)     # 640 % 256
+    assert attn_flash_blocks(1280, 1280) == (256, 256)   # 1280 % 512
+    assert attn_flash_blocks(256, 1024) == (128, 512)    # prefill, sq < sk
+    assert attn_flash_blocks(1536, 1536, 200) == (128, 128)
+    assert attn_flash_blocks(1536, 1536, 300) == (256, 256)
+
+
+def _grads(q, k, v, operand, mode, bq, bk, window):
+    from repro.kernels.flash_attention import flash_attention_mosaic
+
+    def f(q, k, v):
+        o = flash_attention_mosaic(q, k, v, operand, True, window, 0.1,
+                                   mode, 3, 5, 7, bq, bk, None, 0)
+        return jnp.sum(o * jnp.cos(o)), o
+
+    (_, o), g = jax.value_and_grad(f, argnums=(0, 1, 2),
+                                   has_aux=True)(q, k, v)
+    return (o,) + g
+
+
+@pytest.mark.parametrize("mode", ["none", "premask", "replay"])
+@pytest.mark.parametrize("kv, d, window", [(2, 64, 0), (1, 128, 0),
+                                           (2, 64, 300)])
+def test_rule_tiles_match_128_tiles(rng_key, mode, kv, d, window):
+    """At S=1024 the rule's tiles (512 x 512, 256 x 256 under a 300-key
+    window) give the 128 x 128 kernels' output and gradients within
+    float tolerance in every mode (GQA in the D=128 case); replay's
+    bits equal premask's at the rule's tiles, bit for bit."""
+    from repro.core.producer import attn_flash_blocks
+    from repro.kernels.philox_common import seed_salt_smem
+    s = 1024
+    bq, bk = attn_flash_blocks(s, s, window)
+    assert bq > 128 and bk > 128
+    q, k, v = _qkv(rng_key, 1, 2, kv, s, s, d, jnp.float32)
+    operand = {"none": None, "premask": philox_dropout_mask(
+        1, 2, s, s, 0.1, 3, salt=5), "replay": seed_salt_smem(3, 5)}
+    got = _grads(q, k, v, operand[mode], mode, bq, bk, window)
+    want = _grads(q, k, v, operand[mode], mode, 128, 128, window)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
+    if mode == "replay":
+        pre = _grads(q, k, v, operand["premask"], "premask", bq, bk,
+                     window)
+        for a, b in zip(got, pre):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_replay_plane_equals_premask_plane_at_1024():
+    from repro.kernels.flash_attention import replay_keep_plane
+    from repro.kernels.philox_common import seed_salt_smem, unpack_bits_q32
+    s = 1024
+    plane = philox_dropout_mask(1, 2, s, s, 0.1, 3, salt=5)
+    keep = jax.vmap(jax.vmap(lambda m: unpack_bits_q32(m, s)))(plane)
+    np.testing.assert_array_equal(
+        np.asarray(replay_keep_plane(seed_salt_smem(3, 5), 1, 2, s, s,
+                                     0.1)),
+        np.asarray(keep))

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.producer import attn_flash_blocks
 from repro.kernels.flash_attention import flash_attention_mosaic
 from repro.kernels.gemm_rng import gemm_with_rng, gemm_with_rng_grouped
 from repro.kernels.philox import philox_dropout_mask
@@ -63,9 +64,11 @@ def test_flash_fwd_bwd_compiles(one_chip, arch, mode):
     operand = (sds((b, h, s // 32, s), jnp.uint32) if mode == "premask"
                else sds((4,), jnp.uint32))
 
+    bq, bk = attn_flash_blocks(s, s)      # the tiles the model runs
+
     def loss(q, k, v, m):
         o = flash_attention_mosaic(q, k, v, m, True, 0, 0.1, mode, 0, 0,
-                                   7, 128, 128, False, 0)
+                                   7, bq, bk, False, 0)
         return jnp.sum(o.astype(jnp.float32))
 
     _compile(jax.grad(loss, argnums=(0, 1, 2)), q, k, k, operand)

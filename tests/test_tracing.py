@@ -216,12 +216,13 @@ def test_draws_skip_tiles_outside_the_window():
         counts = draw_counts(cfg, _sched(cfg, "qkv", "auto", batch=1,
                                          seq=512))
         per_kernel[name] = {c.kernel: c.live for c in counts}["flash_fwd"]
-    # 4 query blocks: 10 causal tiles a head; the window drops 3 of them
-    # for the local layer (keys 0-127 for queries 384-511 and 256-383,
-    # keys 128-255 for queries 384-511)
+    # a full layer runs 256-blocks at 512: 3 causal tiles a head. The
+    # window caps the local layer's tiles at 128: of 4 query blocks' 10
+    # causal tiles a head it drops 3 (keys 0-127 for queries 384-511 and
+    # 256-383, keys 128-255 for queries 384-511)
     tile = 128 * 128
-    assert per_kernel["full"] == 2 * 2 * 10 * tile
-    assert per_kernel["local"] == 2 * (10 + 7) * tile
+    assert per_kernel["full"] == 2 * 2 * 3 * 4 * tile
+    assert per_kernel["local"] == 2 * (3 * 4 + 7) * tile
 
 
 def test_draws_inert_schedule():
@@ -231,3 +232,30 @@ def test_draws_inert_schedule():
     assert draw_counts(cfg, sched) == ()
     assert live_draw_share(()) is None
     assert explain_draws(()) == "dropout draws a step: none"
+
+
+@pytest.mark.parametrize("seq, n_blk", [(1536, 3), (4096, 8)])
+def test_draws_follow_the_rule_tiles(seq, n_blk):
+    """At the cells' lengths the flash kernels run 512 x 512 tiles: each
+    kernel's live draws are its run tiles x 512 x 512, the line names the
+    tile and the grid steps, and the live share is 3a / (3a + P) for the
+    causal area a and the plane P that the dropped host draws whole."""
+    cfg = _cfg(n_heads=1, n_kv_heads=1, n_layers=2)
+    counts = {c.kernel: c for c in draw_counts(cfg, _sched(
+        cfg, "prev_gemm", "auto", batch=1, seq=seq))}
+    run = n_blk * (n_blk + 1) // 2         # causal tiles a head
+    skipped = n_blk * n_blk - run
+    for k in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        c = counts[k]
+        assert c.tiles == ((512, 512),)
+        assert (c.steps_run, c.steps_skipped) == (2 * run, 2 * skipped)
+        assert c.live == c.steps_run * 512 * 512
+    plane = seq * seq
+    area = run * 512 * 512
+    assert counts["gemm_rng"].dropped == 2 * plane
+    assert live_draw_share(tuple(counts.values())) == pytest.approx(
+        3 * area / (3 * area + plane))
+    line = explain_draws(tuple(counts.values()))
+    assert (f"flash_fwd 2 calls {2 * area / 1e6:.1f}M live 0.0M dropped "
+            f"tile 512x512 grid steps {2 * run} run {2 * skipped} "
+            "skipped") in line
