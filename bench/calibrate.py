@@ -3,7 +3,7 @@
     python3 bench/calibrate.py --workload <cell> --seeds 12 [--first-seed N]
                                [--decay D]
 
-For each seed, in one process: the reference (reference.py) first, then
+For each seed, in one process: the configuration's reference first, then
 the program's checked steps as the benchmark runs them, the control (the
 program's own bfloat16 compute path, ``make_train_step(...,
 compute_dtype=bfloat16)``) and the half-batch fault (faults.py), each

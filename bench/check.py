@@ -26,9 +26,13 @@ round-off alone.
 
 A cell's limits (``bench/limits/<cell>.json``) give each number a limit,
 or ``null`` for a number that has no upper reading and is not compared.
+
+The reference is the module that the configuration file names under
+``"reference"``, a path under ``bench/`` (default ``reference.py``).
 """
 from __future__ import annotations
 
+import importlib
 import statistics
 from typing import Dict, List, Tuple
 
@@ -111,12 +115,23 @@ def compare(prog: dict, ref: dict) -> Tuple[Dict[str, float], dict]:
     return numbers, where
 
 
+def reference_module(cfg: dict):
+    """The plain reference that the configuration file names, a module
+    under ``bench/`` (``reference.py`` is ``bench.reference``)."""
+    rel = cfg.get("reference", "reference.py")
+    parts = rel[:-3].split("/")
+    if not rel.endswith(".py") or not all(p.isidentifier() for p in parts):
+        raise ValueError(f"a reference is a module's .py file under bench/, "
+                         f"not {rel!r}")
+    return importlib.import_module(".".join(["bench"] + parts))
+
+
 def reference_readings(cfg: dict, mix: dict, ring, master_fn, key) -> dict:
     """The reference's readings over the mix's checked steps, from the
     weights ``master_fn(key)`` and the batches ``ring``."""
     import jax
     import jax.numpy as jnp
-    from bench import reference
+    reference = reference_module(cfg)
     n = mix["checked_steps"]
     step = jax.jit(reference.make_step(cfg, mix), donate_argnums=(0, 1, 2))
     master = jax.jit(master_fn)(key)

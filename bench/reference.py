@@ -34,6 +34,12 @@ and the out-projection that hosts the dropout bits with its operands in
 the mix's ``host_dtype`` (operands and result). It runs layer by layer under rematerialization
 and attention one batch row at a time, so that it fits on the chip once
 the program's state is freed.
+
+A configuration file names its reference (``"reference"``, default this
+file); every reference exports ``covers(config)`` and ``make_step(cfg,
+mix)``. Another reference may import the shared parts of this one:
+``keep_bits``, ``_norm``, ``_rope``, ``lr_at``, ``decays`` and the AdamW
+step of ``make_step``, which takes its loss as ``loss_fn``.
 """
 from __future__ import annotations
 
@@ -204,6 +210,18 @@ def _layer(h, lp, cfg, mix, step, layer):
     return h + out
 
 
+def covers(config: dict):
+    """None when this reference computes the configuration as run (the
+    configuration file's keys over every field of the program's model),
+    else the reason it does not."""
+    if config.get("moe") is not None or set(
+            config.get("block_pattern", ["full"])) != {"full"}:
+        return "the reference covers dense full-attention blocks"
+    if config["qkv_bias"] or config["qk_norm"] or config["tie_embeddings"]:
+        return "the reference has no qkv bias, qk-norm or tied embeddings"
+    return None
+
+
 def loss(master, x, y, step, cfg, mix):
     """Mean cross-entropy of one batch."""
     import jax
@@ -211,9 +229,6 @@ def loss(master, x, y, step, cfg, mix):
     (stack,) = master["stacks"]
     if set(stack) != {"l0"}:
         raise ValueError("the reference runs a uniform stack of layers")
-    if cfg["qkv_bias"] or cfg["qk_norm"] or cfg["tie_embeddings"]:
-        raise ValueError("the reference has no qkv bias, qk-norm or tied "
-                         "embeddings")
     h = x if cfg["frontend"] == "embed_stub" else master["embed"][x]
     for layer in range(cfg["n_layers"]):
         lp = jax.tree.map(lambda a, i=layer: a[i], stack["l0"])
@@ -247,16 +262,17 @@ def decays(path) -> bool:
                 or any("norm" in n for n in names))
 
 
-def make_step(cfg: dict, mix: dict):
+def make_step(cfg: dict, mix: dict, loss_fn=loss):
     """train_step(master, m, v, x, y, step) -> (master, m, v, loss,
-    clipped_grads): one reference AdamW step."""
+    clipped_grads): one reference AdamW step on ``loss_fn(master, x, y,
+    step, cfg, mix)``."""
     import jax
     import jax.numpy as jnp
     opt = mix["optimizer"]
 
     def step_fn(master, m, v, x, y, step):
         value, grads = jax.value_and_grad(
-            lambda p_: loss(p_, x, y, step, cfg, mix))(master)
+            lambda p_: loss_fn(p_, x, y, step, cfg, mix))(master)
         gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g))
                              for g in jax.tree.leaves(grads)))
         scale = jnp.minimum(1.0, opt["grad_clip"] / (gnorm + 1e-9))

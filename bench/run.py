@@ -8,6 +8,14 @@ A cell of ``BENCHMARK.json`` names a configuration
 ``bench/limits/<cell>.json`` and each per-layer metric's reader in
 ``bench/metrics/<metric>.py``. Adding a cell adds files and entries only.
 
+A configuration file holds the program's ``--arch``, its ModelConfig
+fields as run (enums by value, tuples as lists, ``moe`` as an object),
+each checked against the program, and the benchmark's own keys
+(``OWN_KEYS``): ``reduced`` and ``published`` (dotted for a key of a
+nested group, ``moe.n_experts``), ``reference`` (its plain reference
+under ``bench/``, default ``reference.py``), ``scopes`` (the program's
+scopes beyond ``bench/scopes.SCOPES``), precision and remat.
+
 One run, in one process:
   1. set-up: the compile cache, the program's run built from the cell's
      flags (``repro.launch.train.build_run``), weights and a ring of
@@ -18,7 +26,8 @@ One run, in one process:
   2. the window: the same runner, one step per call, for --seconds
      (with --trace 1 under the profiler);
   3. the peak device memory, then the program's state is freed and the
-     plain reference (reference.py) runs the checked steps;
+     plain reference that the configuration names (default reference.py)
+     runs the checked steps;
   4. the last line of stdout: one JSON object with ``correct``, the
      metrics and the device. The numbers compared, each with its limit,
      are also the last lines of stderr.
@@ -33,6 +42,8 @@ import time
 T_START = time.perf_counter()      # set-up is timed from here
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
+import enum  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -57,8 +68,27 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 from bench import check, traffic, weights, work  # noqa: E402
 
+SPEC = os.path.join(ROOT, "BENCHMARK.json")
+LIMITS = os.path.join(BENCH, "limits")
+
 END_TO_END = ("train_tokens_per_s", "step_ms_p90", "peak_hbm_gb", "setup_s")
 NEVER = 10 ** 9                    # checkpoint interval beyond any window
+# the benchmark's own keys of a configuration file; every other key names
+# a field of the program's ModelConfig and is checked against it
+OWN_KEYS = ("arch", "reduced", "published", "deployment", "precision",
+            "assumed", "reference", "scopes", "compute_dtype",
+            "matmul_precision", "attention_precision", "remat")
+# ModelConfig fields that every configuration file states
+REQUIRED = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
+            "d_ff", "vocab_size", "ffn", "norm", "norm_eps", "rope",
+            "rope_theta", "frontend", "tie_embeddings", "qkv_bias",
+            "qk_norm")
+# ModelConfig fields that neither the counts nor a reference read: labels,
+# the dropout rates (the mix states dropout) and the rope table's length.
+# Any other field that the program runs at a value other than ModelConfig's
+# default (block_pattern, local_window, moe, ...) has to be stated too.
+UNREAD = ("name", "family", "source", "attn_dropout", "resid_dropout",
+          "max_seq_len")
 
 
 class NoChip(RuntimeError):
@@ -68,7 +98,7 @@ class NoChip(RuntimeError):
 def load_cell(workload: str) -> dict:
     """The cell's configuration, mix, limits and per-layer metric names,
     found by name from BENCHMARK.json."""
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+    with open(SPEC) as f:
         bench = json.load(f)
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
@@ -81,7 +111,7 @@ def load_cell(workload: str) -> dict:
     if config["reduced"] != conf["reduced"]:
         raise ValueError(f"{conf['file']} and BENCHMARK.json list "
                          "different cuts")
-    limits_path = os.path.join(BENCH, "limits", f"{workload}.json")
+    limits_path = os.path.join(LIMITS, f"{workload}.json")
     with open(limits_path) as f:
         limits = json.load(f)
     per_layer = [m["name"] for m in bench["per_layer"]
@@ -118,14 +148,98 @@ def device_info(chips: int) -> dict:
             "count": len(devs)}
 
 
+def plain(value):
+    """A value of the program's config as a configuration file states it:
+    an enum by its value, a tuple as a list, a dataclass as an object."""
+    if isinstance(value, enum.Enum):
+        return value.value
+    if dataclasses.is_dataclass(value):
+        return {f.name: plain(getattr(value, f.name))
+                for f in dataclasses.fields(value)}
+    if isinstance(value, (tuple, list)):
+        return [plain(v) for v in value]
+    return value
+
+
+def apply_cuts(model, config: dict):
+    """``model`` with the configuration's cuts (``reduced``, less
+    ``n_layers``, which the flags set) at the file's values. A dotted cut
+    (``moe.n_experts``) is a key of a nested dataclass."""
+    missing = sorted({k.partition(".")[0] for k in config["reduced"]}
+                     - set(config))
+    if missing:
+        raise ValueError(f"the configuration file leaves out fields that "
+                         f"it cuts: {missing}")
+    top, nested = {}, {}
+    for key in config["reduced"]:
+        group, _, inner = key.partition(".")
+        if inner:
+            nested.setdefault(group, {})[inner] = config[group][inner]
+        elif key != "n_layers":
+            top[key] = config[key]
+    for group, cuts in nested.items():
+        if getattr(model, group) is None:
+            raise ValueError(f"a cut of {group}, which the program's "
+                             "model does not have")
+        top[group] = dataclasses.replace(getattr(model, group), **cuts)
+    return dataclasses.replace(model, **top)
+
+
+def unstated(model, config: dict) -> list:
+    """The fields that the configuration file has to state and leaves out:
+    every one of ``REQUIRED``, every other field outside ``UNREAD`` that
+    the program runs at a value other than ModelConfig's default, and
+    every key of a nested group that it states (a group is copied
+    whole)."""
+    missing = [k for k in REQUIRED if k not in config]
+    for f in dataclasses.fields(model):
+        if f.name in REQUIRED or f.name in UNREAD:
+            continue
+        value = getattr(model, f.name)
+        if f.name not in config and value != f.default:
+            missing.append(f.name)
+        elif dataclasses.is_dataclass(value) and isinstance(
+                config.get(f.name), dict):
+            missing += [f"{f.name}.{g.name}" for g in
+                        dataclasses.fields(value)
+                        if g.name not in config[f.name]]
+    return missing
+
+
+def stated_mismatch(model, config: dict) -> dict:
+    """{key: (stated, run)} of every key of the configuration file that
+    the program runs otherwise; a nested group (``moe``) is compared key
+    by key. A key that is neither the benchmark's own nor a field of the
+    program's model, or a field that the file has to state and leaves out
+    (``unstated``), is an error."""
+    got = plain(model)
+    unknown = sorted(k for k in config if k not in OWN_KEYS and k not in got)
+    mismatch = {}
+    for key in (k for k in config if k not in OWN_KEYS and k in got):
+        want, have = config[key], got[key]
+        if isinstance(want, dict) and isinstance(have, dict):
+            unknown += [f"{key}.{k}" for k in want if k not in have]
+            mismatch.update({f"{key}.{k}": (v, have[k])
+                             for k, v in want.items()
+                             if k in have and v != have[k]})
+        elif want != have:
+            mismatch[key] = (want, have)
+    if unknown:
+        raise ValueError(f"configuration keys that name no field of the "
+                         f"program's model: {unknown}")
+    missing = unstated(model, config)
+    if missing:
+        raise ValueError(f"the configuration file leaves out fields that "
+                         f"the program runs: {missing}")
+    return mismatch
+
+
 def build_run(config: dict, mix: dict, seed: int, ckpt_dir: str):
     """The program's RunConfig for the cell, built from its flags the way
-    ``launch/train.py`` builds it, with the configuration's cuts applied
-    and every stated value checked against what the program runs."""
-    import dataclasses
-
+    ``launch/train.py`` builds it, with the configuration's cuts applied,
+    every stated value checked against what the program runs, and the
+    configuration's reference asked whether it covers the model."""
     import jax
-    from repro.config.base import AttentionKind
     from repro.launch import train
     flags = ["--arch", config["arch"], "--layers", str(config["n_layers"]),
              "--batch", str(mix["batch"]), "--seq", str(mix["seq"]),
@@ -139,20 +253,13 @@ def build_run(config: dict, mix: dict, seed: int, ckpt_dir: str):
         raise ValueError(f"the configuration states matmul precision "
                          f"{config['matmul_precision']!r}; the program runs "
                          f"{jax.config.jax_default_matmul_precision!r}")
-    model = run.model
-    cuts = {k: config[k] for k in config["reduced"] if k != "n_layers"}
-    model = dataclasses.replace(model, **cuts)
-    stated = {k: config[k] for k in (
-        "n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim", "d_ff",
-        "vocab_size", "norm_eps", "rope", "rope_theta", "frontend",
-        "tie_embeddings", "qkv_bias", "qk_norm")}
-    stated.update(ffn=config["ffn"], norm=config["norm"])
-    got = {k: getattr(model, k) for k in stated}
-    got.update(ffn=model.ffn.value, norm=model.norm.value)
-    mismatch = {k: (stated[k], got[k]) for k in stated if stated[k] != got[k]}
-    if model.moe is not None or set(model.block_pattern) != {
-            AttentionKind.FULL}:
-        mismatch["block"] = "the reference covers dense full-attention blocks"
+    model = apply_cuts(run.model, config)
+    mismatch = stated_mismatch(model, config)
+    # the reference sees the configuration as run: its file's keys over
+    # every field of the program's model
+    why = check.reference_module(config).covers({**plain(model), **config})
+    if why:
+        mismatch["reference"] = why
     d = run.dropout
     for k, v in mix["dropout"].items():
         if k != "host_dtype" and getattr(d, k) != v:

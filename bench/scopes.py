@@ -3,13 +3,16 @@
 The program names its layers with ``jax.named_scope``: ``embed``,
 ``layers`` (the stack scans' own slices and stacked writes), ``attn`` and
 ``ffn`` in each block, ``mask`` (the bootstrap producer), ``unembed``,
-``loss`` and ``optimizer``. A scope is metadata of the optimized HLO: each
-instruction's ``op_name`` holds the scope path, wrapped in ``jvp(...)`` in
-the forward and in ``transpose(jvp(...))`` in the backward. A device op of
-the trace is named by its HLO instruction, so the step's optimized HLO
-text maps it to a (scope, direction). A fusion takes the scope of the
-first dot or convolution it computes, else its own; an op the compiler
-made without metadata takes the scope of the first op that reads it.
+``loss`` and ``optimizer``; a configuration file's ``"scopes"`` list
+names those the program gives its layers beyond these, which a cell of
+that configuration attributes too. A scope is metadata of the optimized
+HLO: each instruction's ``op_name`` holds the scope path, wrapped in
+``jvp(...)`` in the forward and in ``transpose(jvp(...))`` in the
+backward. A device op of the trace is named by its HLO instruction, so
+the step's optimized HLO text maps it to a (scope, direction). A fusion
+takes the scope of the first dot or convolution it computes, else its
+own; an op the compiler made without metadata takes the scope of the
+first op that reads it.
 
 The program's runner names its host time with spans inside the profiler's
 step markers (``train``): ``runner.batch``, ``runner.step`` (the
@@ -63,18 +66,25 @@ _NAME = re.compile(r"%([\w.\-]+)")
 _BODY = re.compile(r"body=%?([\w.\-]+)")
 
 
-def scope_of(op_name: str) -> Optional[Tuple[str, str]]:
-    """(innermost program scope, "fwd" | "bwd") of an ``op_name``, or
-    None when it holds no scope. Transformation wrappers (``jvp(...)``,
-    ``transpose(...)``) are looked through; a ``jit(...)`` is a function's
-    name and never a scope."""
+def cell_scopes(config: dict) -> Tuple[str, ...]:
+    """The scopes a cell of ``config`` attributes: the shared ones and
+    the configuration's own."""
+    return SCOPES + tuple(config.get("scopes", ()))
+
+
+def scope_of(op_name: str, names: Sequence[str] = SCOPES
+             ) -> Optional[Tuple[str, str]]:
+    """(innermost program scope among ``names``, "fwd" | "bwd") of an
+    ``op_name``, or None when it holds no scope. Transformation wrappers
+    (``jvp(...)``, ``transpose(...)``) are looked through; a ``jit(...)``
+    is a function's name and never a scope."""
     found = None
     for part in op_name.split("/"):
         m = _WRAPPER.match(part)
         while m:
             part = m.group(1)
             m = _WRAPPER.match(part)
-        if part in SCOPES:
+        if part in names:
             found = part
     if found is None:
         return None
@@ -95,9 +105,11 @@ def _operands(line: str) -> List[str]:
     return []
 
 
-def hlo_scopes(hlo_text: str) -> Dict[str, Tuple[str, str]]:
-    """HLO instruction name -> (scope, direction), for every instruction
-    of the optimized module's text whose scope is known.
+def hlo_scopes(hlo_text: str, names: Sequence[str] = SCOPES
+               ) -> Dict[str, Tuple[str, str]]:
+    """HLO instruction name -> (scope among ``names``, direction), for
+    every instruction of the optimized module's text whose scope is
+    known.
 
     A fusion takes the scope of the first dot or convolution it computes,
     else its own. An op the compiler made without metadata (a weight's
@@ -124,7 +136,7 @@ def hlo_scopes(hlo_text: str) -> Dict[str, Tuple[str, str]]:
         for _, line in comps.get(comp, ()):
             if _MATMUL.search(line):
                 m = _OP_NAME.search(line)
-                got = scope_of(m.group(1)) if m else None
+                got = scope_of(m.group(1), names) if m else None
                 if got is not None:
                     return got
             called = _CALLS.search(line)
@@ -142,7 +154,7 @@ def hlo_scopes(hlo_text: str) -> Dict[str, Tuple[str, str]]:
                    if called and " fusion(" in line else None)
             if got is None:
                 m = _OP_NAME.search(line)
-                got = scope_of(m.group(1)) if m else None
+                got = scope_of(m.group(1), names) if m else None
             if got is not None:
                 out[name] = got
 
@@ -280,8 +292,9 @@ def cell_scope_seconds(ctx: dict) -> Dict[str, float]:
     milliseconds a step."""
     if "scope_s" not in ctx:
         tr = ctx["trace"]
+        config = ctx["config"]
         secs = scope_seconds(tr.op_s, hlo_scopes(
-            step_hlo(ctx["config"], ctx["mix"])))
+            step_hlo(config, ctx["mix"]), cell_scopes(config)))
         ctx["scope_s"] = {k: v / tr.chips for k, v in secs.items()}
         per_step = {k: round(1e3 * v / max(ctx["steps"], 1), 3)
                     for k, v in sorted(ctx["scope_s"].items())}
@@ -344,7 +357,7 @@ def attribute(cell: dict, seed: int, seconds: float, trace_dir: str,
     done = runner.run(mix["checked_steps"]
                       + mix["untimed_steps"]).steps_completed
     scopes = hlo_scopes(step.lower(runner.state, *ring[0])
-                        .compile().as_text())
+                        .compile().as_text(), cell_scopes(config))
 
     jax.profiler.start_trace(trace_dir)
     times.times.clear()

@@ -46,11 +46,29 @@ def test_names_and_references():
     assert all(m["moves"] in moves for m in SPEC["per_layer"])
 
 
+WIDTHS = ("d_model", "d_ff", "head_dim", "d_ff_expert", "top_k",
+          "local_window", "dense_residual_ff", "rwkv_head_dim")
+
+
+def names_a_width(key: str) -> bool:
+    """A cut that changes a width; for a dotted cut (``moe.d_ff_expert``)
+    its last part is the key."""
+    last = key.split(".")[-1]
+    return last.endswith(("_dim", "_rank")) or last in WIDTHS
+
+
+@pytest.mark.parametrize("key, width", [
+    ("n_layers", False), ("moe.n_experts", False), ("vocab_size", False),
+    ("d_model", True), ("moe.d_ff_expert", True), ("moe.top_k", True),
+    ("kv_lora_rank", True), ("local_window", True)])
+def test_width_rule(key, width):
+    assert names_a_width(key) is width
+
+
 def test_configs_state_their_cuts():
     for conf in SPEC["configs"]:
         with open(os.path.join(ROOT, conf["file"])) as f:
             config = json.load(f)
         assert config["reduced"] == conf["reduced"]
         for key in conf["reduced"]:
-            assert not key.endswith(("_dim", "_rank"))
-            assert key not in ("d_model", "d_ff", "head_dim")
+            assert not names_a_width(key), key

@@ -7,7 +7,15 @@ from bench.tests.common import tiny_cell
 
 
 def test_control_and_fault_readings():
-    cell = tiny_cell("tiny-yi")
+    control_and_fault_readings("tiny-yi")
+
+
+def test_control_and_fault_readings_nodrop():
+    control_and_fault_readings("tiny-musicgen-nodrop")
+
+
+def control_and_fault_readings(cell_name):
+    cell = tiny_cell(cell_name)
     (row,) = calibrate.readings(cell, [2 ** 32 + 5], decay=0.1)
     for name in ("program", "control", "half_batch"):
         assert set(row[name]) == set(check.NUMBERS)

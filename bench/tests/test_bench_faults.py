@@ -13,6 +13,9 @@ from bench.tests.common import tiny_cell
     ("tiny-musicgen", None),
     ("tiny-musicgen", "unchanged"),
     ("tiny-musicgen", "half_batch"),
+    ("tiny-musicgen-nodrop", None),
+    ("tiny-musicgen-nodrop", "unchanged"),
+    ("tiny-musicgen-nodrop", "half_batch"),
     ("tiny-yi", None),
     ("tiny-yi", "unchanged"),
     ("tiny-yi", "half_batch"),

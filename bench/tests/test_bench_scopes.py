@@ -43,6 +43,23 @@ def test_scope_of(op_name, want):
     assert scopes.scope_of(op_name) == want
 
 
+EXPERTS = ("jit(train_step)/transpose(jvp(layers))/while/body/closed_call/"
+           "ffn/experts/dot_general")
+
+
+@pytest.mark.parametrize("op_name, config, want", [
+    (EXPERTS, {}, ("ffn", "bwd")),
+    (EXPERTS, {"scopes": []}, ("ffn", "bwd")),
+    (EXPERTS, {"scopes": ["experts"]}, ("experts", "bwd")),
+    ("jit(train_step)/jvp(layers)/experts/ffn/dot_general",
+     {"scopes": ["experts"]}, ("ffn", "fwd")),
+])
+def test_scope_of_declared(op_name, config, want):
+    """A configuration's own scope counts where it declares it, and the
+    innermost scope still wins."""
+    assert scopes.scope_of(op_name, scopes.cell_scopes(config)) == want
+
+
 def test_fusions_take_their_matmul_scope(fixture_scopes):
     got = fixture_scopes
     # the stacked write's own metadata is the scan's; its convolution is
@@ -165,3 +182,10 @@ def test_label_gaps_innermost_program_span():
 def test_idle_gaps_of_one_chip():
     events = [("a", 0, 10), ("b", 5, 10), ("c", 30, 5), ("d", 90, 20)]
     assert scopes.idle_gaps(events, 0, 100) == [(35, 90), (15, 30)]
+
+
+def test_undeclared_scope_list_changes_nothing(fixture_scopes):
+    with open(os.path.join(HERE, "data", "tiny-yi-step.v5e.hlo.txt")) as f:
+        text = f.read()
+    assert scopes.hlo_scopes(
+        text, scopes.cell_scopes({"scopes": ["experts"]})) == fixture_scopes

@@ -35,10 +35,16 @@ def roofline_s(flops: float, nbytes: float, peak: dict) -> float:
 
 
 def attention_flops(b: int, hq: int, s: int, d: int,
-                    causal: bool = True) -> float:
+                    causal: bool = True, window: int = 0) -> float:
     """One attention layer's forward and backward: the forward's two
     S x S matmuls (q.k and p.v) and the backward's four (dv, dp, dq,
-    dk), each 2*B*Hq*S*S*D, halved under a causal mask."""
+    dk), each 2*B*Hq*S*S*D, halved under a causal mask. A sliding window
+    of W (query q sees key k when q - W < k <= q, as the flash kernel
+    masks it) keeps S*W - W*W/2 of the pairs in place of S*S/2; the two
+    agree at W = S."""
+    if window:
+        w = min(window, s)
+        return 6.0 * 2.0 * b * hq * (s * w - w * w / 2.0) * d
     per_matmul = 2.0 * b * hq * s * s * d * (0.5 if causal else 1.0)
     return 6.0 * per_matmul
 
@@ -64,37 +70,77 @@ def gemm_bytes(m: int, n: int, k: int, in_itemsize: int,
     return float(in_itemsize * (m * k + k * n) + out_itemsize * m * n)
 
 
-def matmul_params(model: dict) -> int:
+def layer_windows(model: dict) -> dict:
+    """{attention window: number of layers}, 0 for full causal attention:
+    ``block_pattern`` (default ``["full"]``) repeated over ``n_layers`` as
+    the program's ``ModelConfig.layer_kinds`` repeats it, a ``local``
+    layer at ``local_window``."""
+    pattern = model.get("block_pattern", ["full"])
+    out: dict = {}
+    for i in range(model["n_layers"]):
+        kind = pattern[i % len(pattern)]
+        if kind not in ("full", "local"):
+            raise ValueError(f"no attention count for {kind!r} layers")
+        w = model.get("local_window", 0) if kind == "local" else 0
+        out[w] = out.get(w, 0) + 1
+    return out
+
+
+def matmul_params(model: dict) -> float:
     """Parameters that enter a matmul per token: the attention
     projections, the FFN and the output head. The embedding lookup is a
-    gather, and norms and biases are elementwise."""
+    gather, and norms and biases are elementwise.
+
+    With ``moe``, every layer from ``moe.first_dense_layers`` on has, in
+    place of the ``d_ff`` FFN, the router (d_model x the published expert
+    count: ``published["moe.n_experts"]``, else ``moe.n_experts``), the
+    shared experts whole, and the routed experts at top_k x held /
+    published expert FFNs a token: the expected share of this chip's
+    experts under even routing."""
     d, hd = model["d_model"], model["head_dim"]
     nq, nkv = model["n_heads"], model["n_kv_heads"]
     ffn_mats = 3 if model["ffn"] in ("swiglu", "geglu") else 2
-    per_layer = d * nq * hd * 2 + d * nkv * hd * 2 + ffn_mats * d * model["d_ff"]
-    return model["n_layers"] * per_layer + d * model["vocab_size"]
+    attn = d * nq * hd * 2 + d * nkv * hd * 2
+    dense_ffn = ffn_mats * d * model["d_ff"]
+    head = d * model["vocab_size"]
+    moe = model.get("moe")
+    if moe is None:
+        return model["n_layers"] * (attn + dense_ffn) + head
+    if moe.get("dense_residual"):
+        raise ValueError("no count for a dense residual FFN beside experts")
+    n_dense = min(moe.get("first_dense_layers", 0), model["n_layers"])
+    held = moe["n_experts"]
+    published = model.get("published", {}).get("moe.n_experts", held)
+    expert = ffn_mats * d * moe["d_ff_expert"]
+    moe_ffn = (d * published + moe.get("n_shared_experts", 0) * expert
+               + moe["top_k"] * held / published * expert)
+    return (model["n_layers"] * attn + n_dense * dense_ffn
+            + (model["n_layers"] - n_dense) * moe_ffn + head)
 
 
 def model_flops_per_step(model: dict, batch: int, seq: int) -> float:
     """Model FLOPs of one training step: 6 per matmul parameter per token
-    (forward 2, backward 4) plus causal attention's score matmuls.
-    Recomputation is not counted."""
+    (forward 2, backward 4) plus each layer's attention score matmuls,
+    causal or windowed. Recomputation is not counted."""
     dense = 6.0 * matmul_params(model) * batch * seq
-    attn = model["n_layers"] * attention_flops(
-        batch, model["n_heads"], seq, model["head_dim"])
+    attn = sum(n * attention_flops(batch, model["n_heads"], seq,
+                                   model["head_dim"], window=w)
+               for w, n in layer_windows(model).items())
     return dense + attn
 
 
 def attention_roofline_s(model: dict, batch: int, seq: int,
                          peak: dict) -> float:
     """Least time for one step's attention, forward and backward, over
-    every layer, with operands in the configuration's compute dtype."""
+    every layer (causal or windowed), with operands in the
+    configuration's compute dtype."""
     itemsize = DTYPE_BYTES[model["compute_dtype"]]
-    one = roofline_s(
-        attention_flops(batch, model["n_heads"], seq, model["head_dim"]),
-        attention_bytes(batch, model["n_heads"], model["n_kv_heads"], seq,
-                        model["head_dim"], itemsize), peak)
-    return model["n_layers"] * one
+    nbytes = attention_bytes(batch, model["n_heads"], model["n_kv_heads"],
+                             seq, model["head_dim"], itemsize)
+    return sum(n * roofline_s(
+        attention_flops(batch, model["n_heads"], seq, model["head_dim"],
+                        window=w), nbytes, peak)
+        for w, n in layer_windows(model).items())
 
 
 def out_proj_roofline_s(model: dict, batch: int, seq: int,
